@@ -45,7 +45,6 @@ def _parity_check(T: int = 32, tile: int = 8, gamma: float = 0.3):
     """f64 E parity + f32 grad parity on a random sparse support."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from repro.core import SparsePaths, block_sparsify
     from repro.core.softdtw import soft_alignment, soft_wdtw
     from repro.kernels.soft_block import (soft_alignment_pairs,
@@ -59,7 +58,7 @@ def _parity_check(T: int = 32, tile: int = 8, gamma: float = 0.3):
                      counts=jnp.asarray(w), theta=0.0, gamma=0.0)
     bsp = block_sparsify(sp, tile=tile)
     xs, ys = rng.normal(size=(4, T)), rng.normal(size=(4, T))
-    with enable_x64():
+    with jax.enable_x64(True):
         x64, y64 = jnp.asarray(xs), jnp.asarray(ys)
         w64 = jnp.asarray(np.asarray(w, np.float64))
         Eb = np.asarray(soft_alignment_pairs(x64, y64, bsp, gamma,

@@ -307,8 +307,7 @@ class SimilarityEngine:
 
     def soft_gram(self, A, B=None) -> jnp.ndarray:
         """Differentiable all-pairs soft Gram matrix at the spec's
-        ``gamma`` (fused Pallas backward on TPU, reverse scan
-        elsewhere)."""
+        ``gamma`` (block-sparse scan forward, reverse-scan backward)."""
         from repro.kernels.soft_block import soft_spdtw_gram_batch
         return soft_spdtw_gram_batch(jnp.asarray(A, jnp.float32),
                                      self._corpus_or(B),

@@ -67,10 +67,13 @@ SHARDED = "sharded"                    # cascade runs fully traced under
 #                                        (the sharded serving tier,
 #                                        DESIGN.md §15); the dense oracle
 #                                        is host-only for serving
+WAVEFRONT = "wavefront"                # plan-free DTW / banded DTW /
+#                                        K_rdtw sweeps (pairs and the
+#                                        K_rdtw Gram)
 
 CAPABILITIES = (DIFFERENTIABLE, MULTIVARIATE, MULTIVARIATE_GRAD,
                 EARLY_ABANDON, PRUNED_DP, TRACED_WEIGHTS, ANCHOR_EMBED,
-                SHARDED)
+                SHARDED, WAVEFRONT)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,25 +122,30 @@ def available_backends() -> Tuple[str, ...]:
 register_backend(Backend(
     name="dense",
     caps=frozenset({DIFFERENTIABLE, MULTIVARIATE, MULTIVARIATE_GRAD,
-                    TRACED_WEIGHTS, ANCHOR_EMBED}),
+                    TRACED_WEIGHTS, ANCHOR_EMBED, WAVEFRONT}),
     fallback=None,
     description="chunked nested-vmap over the core DPs; fully traceable "
                 "(the only path for traced weight grids) and the oracle"))
 register_backend(Backend(
     name="scan",
     caps=frozenset({DIFFERENTIABLE, MULTIVARIATE, MULTIVARIATE_GRAD,
-                    EARLY_ABANDON, PRUNED_DP, ANCHOR_EMBED, SHARDED}),
+                    EARLY_ABANDON, PRUNED_DP, ANCHOR_EMBED, SHARDED,
+                    WAVEFRONT}),
     fallback="dense",
     description="lax.scan over the active-tile schedule; CPU/GPU "
                 "production path, work scales with surviving tiles"))
+# The pallas record lists only what compiles for the chip (tests/
+# test_tpu_compile.py): the hard SP-DTW Gram and pair kernels. The soft
+# forward/stash/backward kernels (DIFFERENTIABLE) and the wavefront /
+# banded / K_rdtw kernels (WAVEFRONT) still read values at traced lane
+# offsets, which Mosaic does not lower, so those calls resolve to scan.
 register_backend(Backend(
     name="pallas",
-    caps=frozenset({DIFFERENTIABLE, MULTIVARIATE, EARLY_ABANDON,
-                    PRUNED_DP, ANCHOR_EMBED, SHARDED}),
+    caps=frozenset({MULTIVARIATE, EARLY_ABANDON, PRUNED_DP, ANCHOR_EMBED,
+                    SHARDED}),
     fallback="scan",
-    description="fused Pallas kernels (compiled on TPU, interpret "
-                "elsewhere); the soft backward kernel is univariate, so "
-                "multivariate gradients fall back to scan"))
+    description="fused Pallas SP-DTW kernels (compiled on TPU, interpret "
+                "elsewhere); soft and wavefront sweeps fall back to scan"))
 
 # legacy spelling accepted everywhere an ``impl=`` flows in
 _ALIASES = {"ref": "scan"}
@@ -294,6 +302,17 @@ def to_tile_major(X, S: int, Tp: int, n_to: Optional[int] = None,
     Ti = Tp // S
     return Xp.reshape(n_to, Ti, S, d).transpose(0, 1, 3, 2) \
              .reshape(n_to, Ti * d * S)
+
+
+def to_tile_stack(X, S: int, Tp: int, n_to: Optional[int] = None
+                  ) -> jnp.ndarray:
+    """``to_tile_major`` with the tile index moved to the leading axis:
+    (Tp // S, n_to or N, d*S). The Pallas kernels block this layout, so a
+    tile is addressed on a leading axis and every block's lane extent is
+    the whole d*S — no dynamic lane offset, which Mosaic cannot prove
+    aligned for S < 128."""
+    Xt = to_tile_major(X, S, Tp, n_to=n_to)
+    return Xt.reshape(Xt.shape[0], Tp // S, -1).transpose(1, 0, 2)
 
 
 def from_tile_major(G: jnp.ndarray, S: int, d: int, T: int,

@@ -10,7 +10,9 @@ SP-DTW Gram kernel (``gram_spdtw_block``)
   * grid = (A-tile, B-tile, active-path-tile); the innermost axis sweeps the
     row-major schedule of active S x S weight tiles (scalar-prefetched meta:
     ti, tj, slot, top/left/diag-active bits);
-  * each (ba, Tp) A-stripe / (bb, Tp) B-stripe is block-specced with an index
+  * each (Ti, ba, d*S) A-stripe / (Ti, bb, d*S) B-stripe (tile-stacked,
+    ``backends.to_tile_stack``; ba x bb = 8 x 128 keeps the per-pair
+    blocks lane-legal) is block-specced with an index
     map constant in the inner axes, so Pallas's pipeline loads it into VMEM
     **once** per (A-tile, B-tile) step and revisits it for the whole active
     sweep — no HBM pair expansion ever exists;
@@ -95,6 +97,15 @@ def _pair_batch(xa: jnp.ndarray, yb: jnp.ndarray, ba: int, bb: int):
 # SP-DTW: (A-tile, B-tile, active-tile) fused Pallas kernel
 # ---------------------------------------------------------------------------
 
+def _pair_column(m: jnp.ndarray) -> jnp.ndarray:
+    """(ba, bb) per-pair block -> (ba*bb, 1) column, pair p = ia*bb + ib.
+
+    The same values as ``m.reshape(-1, 1)``, spelled as row transposes and
+    an aligned sublane concat: Mosaic has no lane-to-sublane shape cast."""
+    return jnp.concatenate([m[i:i + 1, :].T for i in range(m.shape[0])],
+                           axis=0)
+
+
 def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
                        out_ref, row_edge, col_edge, corner_next, d_ri, alive,
                        *, S: int, g_out: int, ri: int, rj: int,
@@ -109,8 +120,8 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
         # meaningful (entries of never-written columns would otherwise be
         # stale cross-block data); alive starts from the cascade's
         # bound-stage survivors (all-ones when no cascade is running)
-        row_edge[...] = jnp.full((bt, row_edge.shape[1]), INF, jnp.float32)
-        alive[...] = alive0_ref[...].reshape(bt, 1)
+        row_edge[...] = jnp.full(row_edge.shape, INF, jnp.float32)
+        alive[...] = _pair_column(alive0_ref[...])
 
     # early-abandon check at the first tile of each new tile row: the
     # previous tile row is complete, so the running row-min is an
@@ -121,7 +132,8 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
 
     @pl.when(row_first & (g > 0) & (g <= g_out))
     def _():
-        bound = jnp.min(row_edge[...], axis=1, keepdims=True)     # (bt, 1)
+        bound = jnp.min(jnp.min(row_edge[...], axis=0), axis=1,
+                        keepdims=True)                            # (bt, 1)
         alive[...] = alive[...] * (bound <= thr_p).astype(jnp.float32)
 
     tj = meta_ref[g, 1]
@@ -131,8 +143,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
 
     # --- gather incoming edges (guarded against inactive neighbours) ---
     inf_row = jnp.full((bt, S), INF, jnp.float32)
-    top_raw = pl.load(row_edge, (slice(None), pl.dslice(tj * S, S)))
-    top_vec = jnp.where(top_ok, top_raw, inf_row)
+    top_vec = jnp.where(top_ok, row_edge[tj], inf_row)
     left_vec = jnp.where(left_ok, col_edge[...], inf_row)
     c_first = jnp.where(
         g == 0, jnp.zeros((bt, 1), jnp.float32),
@@ -140,10 +151,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
                   jnp.where(left_ok, corner_next[...],
                             # guarded: only read when diag_ok (=> tj > 0);
                             # clamp keeps the untaken branch in-bounds
-                            pl.load(row_edge,
-                                    (slice(None),
-                                     pl.dslice(jnp.maximum(tj * S - 1, 0),
-                                               1)))),
+                            row_edge[jnp.maximum(tj - 1, 0)][:, S - 1:S]),
                   jnp.full((bt, 1), INF, jnp.float32)))
     new_corner = top_vec[:, S - 1:S]
 
@@ -164,9 +172,9 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
     @pl.when(do_sweep)
     def _():
         ti = meta_ref[g, 0]
-        # tile-major layout: tile ti's d channel planes are contiguous
-        xa = pl.load(a_ref, (slice(None), pl.dslice(ti * d * S, d * S)))
-        yb = pl.load(b_ref, (slice(None), pl.dslice(tj * d * S, d * S)))
+        # tile-stacked layout: tile ti's d channel planes are one slab
+        xa = a_ref[ti]                                             # (ba, d*S)
+        yb = b_ref[tj]                                             # (bb, d*S)
         x, y = _pair_batch(xa, yb, ba, bb)                         # (bt, d*S)
         w = w_ref[0]                                               # (S, S)
 
@@ -176,7 +184,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
 
         # --- publish edges for downstream tiles of this pair block ---
         corner_next[...] = new_corner
-        pl.store(row_edge, (slice(None), pl.dslice(tj * S, S)), d_last)
+        row_edge[tj] = d_last
         col_edge[...] = rightcol
         d_ri[...] = dri
 
@@ -187,7 +195,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
             # rows (never stale state — downstream tiles of this pair
             # block consume these edges)
             corner_next[...] = new_corner
-            pl.store(row_edge, (slice(None), pl.dslice(tj * S, S)), inf_row)
+            row_edge[tj] = inf_row
             col_edge[...] = inf_row
             d_ri[...] = inf_row
 
@@ -197,7 +205,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
     # pairs report +INF (their lanes may hold garbage from skipped sweeps)
     @pl.when(g == g_out)
     def _():
-        res = jax.lax.dynamic_slice_in_dim(d_ri[...], rj, 1, axis=1)
+        res = d_ri[:, rj:rj + 1]
         ok = alive[...].reshape(ba, bb) > 0
         out_ref[...] = jnp.where(ok, res.reshape(ba, bb), INF)
 
@@ -207,9 +215,8 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
                                     "ba", "bb", "d", "prune", "interpret"))
 def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
                      g_out, ba, bb, d, prune, interpret):
-    Nap, Tw = A.shape
-    Nbp = B.shape[0]
-    Tp = Tw // d                    # DP grid edge (padded)
+    Ti, Nap, _ = A.shape            # tile-stacked: (Ti, Nap, d*S)
+    Nbp = B.shape[1]
     last = T_orig - 1
     ri, rj = last % S, last % S
     grid = (Nap // ba, Nbp // bb, n_active)
@@ -221,15 +228,15 @@ def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
         in_specs=[
             # index maps constant in the inner axes: each stripe is copied to
             # VMEM once per (A-tile, B-tile) and revisited for every g
-            pl.BlockSpec((ba, Tw), lambda i, j, g, m: (i, 0)),
-            pl.BlockSpec((bb, Tw), lambda i, j, g, m: (j, 0)),
+            pl.BlockSpec((Ti, ba, d * S), lambda i, j, g, m: (0, i, 0)),
+            pl.BlockSpec((Ti, bb, d * S), lambda i, j, g, m: (0, j, 0)),
             pl.BlockSpec((1, S, S), lambda i, j, g, m: (m[g, 2], 0, 0)),
             pl.BlockSpec((ba, 1), lambda i, j, g, m: (i, 0)),    # thresholds
             pl.BlockSpec((ba, bb), lambda i, j, g, m: (i, j)),   # alive0
         ],
         out_specs=pl.BlockSpec((ba, bb), lambda i, j, g, m: (i, j)),
         scratch_shapes=[
-            pltpu.VMEM((ba * bb, Tp), jnp.float32),   # row_edge
+            pltpu.VMEM((Ti, ba * bb, S), jnp.float32),  # row_edge
             pltpu.VMEM((ba * bb, S), jnp.float32),    # col_edge
             pltpu.VMEM((ba * bb, 1), jnp.float32),    # corner_next
             pltpu.VMEM((ba * bb, S), jnp.float32),    # d_ri capture
@@ -271,7 +278,7 @@ def _pad_abandon_state(thresholds, alive0, Na, Nb, Nap, Nbp):
 
 
 def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
-                     T_orig: int | None = None, ba: int = 8, bb: int = 8,
+                     T_orig: int | None = None, ba: int = 8, bb: int = 128,
                      thresholds: jnp.ndarray | None = None,
                      alive0: jnp.ndarray | None = None,
                      interpret: bool = False) -> jnp.ndarray:
@@ -287,7 +294,7 @@ def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
     value exceeds the threshold may report +INF, entries at or below it
     are bit-identical to the exact sweep.
     """
-    from .backends import series_dim, to_tile_major
+    from .backends import series_dim, to_tile_stack
     Na, T = A.shape[0], A.shape[1]
     Nb = B.shape[0]
     d = series_dim(A)
@@ -302,8 +309,8 @@ def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
     Nbp = ((Nb + bb - 1) // bb) * bb
     thr, alive = _pad_abandon_state(thresholds, alive0, Na, Nb, Nap, Nbp)
     out = _gram_spdtw_call(
-        jnp.asarray(meta), to_tile_major(A, bsp.tile, bsp.T, n_to=Nap),
-        to_tile_major(B, bsp.tile, bsp.T, n_to=Nbp), jnp.asarray(bsp.blocks),
+        jnp.asarray(meta), to_tile_stack(A, bsp.tile, bsp.T, n_to=Nap),
+        to_tile_stack(B, bsp.tile, bsp.T, n_to=Nbp), jnp.asarray(bsp.blocks),
         thr, alive, S=bsp.tile, n_active=n_active, T_orig=T_orig,
         g_out=g_out, ba=ba, bb=bb, d=d, prune=thresholds is not None,
         interpret=interpret)

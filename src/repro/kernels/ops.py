@@ -3,10 +3,11 @@
 Backend policy lives in ``repro.kernels.backends``: every ``impl=``
 argument ("auto" | "pallas" | "scan" | "ref" (alias) | "dense") is
 interpreted by ``backends.resolve`` — one auditable capability lookup
-(on TPU the Pallas kernels run compiled; elsewhere the scan engines are
-the default and ``impl="pallas"`` forces interpret mode, which is what
-the correctness tests sweep; traced weight grids and other unsupported
-requirements walk the fallback chain down to the dense oracle).
+(on TPU the Pallas SP-DTW kernels run compiled; elsewhere the scan
+engines are the default and ``impl="pallas"`` forces interpret mode,
+which is what the correctness tests sweep; soft and wavefront calls,
+traced weight grids and other unsupported requirements walk the
+fallback chain to scan or the dense oracle).
 
 The supported public API is the fitted engine
 (``repro.core.engine.fit`` → ``SimilarityEngine``); the module-level
@@ -83,7 +84,8 @@ def _series_d(x) -> int:
 
 def _dtw_pairs(x: jnp.ndarray, y: jnp.ndarray, impl: str = "auto",
                radius: Optional[int] = None) -> jnp.ndarray:
-    require = (bk.MULTIVARIATE,) if _series_d(x) > 1 else ()
+    require = (bk.WAVEFRONT,) + ((bk.MULTIVARIATE,) if _series_d(x) > 1
+                                 else ())
     backend = bk.resolve(impl, require=require).name
     # the wavefront kernel is univariate; scan/dense route to the vmapped
     # core DP (full support => no tiles to skip)
@@ -105,7 +107,7 @@ def dtw_pairs(x: jnp.ndarray, y: jnp.ndarray, impl: str = "auto",
 def dtw_banded_pairs(x: jnp.ndarray, y: jnp.ndarray, radius: int,
                      impl: str = "auto") -> jnp.ndarray:
     """Batched banded DTW via the slanted-strip kernel (O(T*(2r+1)) work)."""
-    backend = bk.resolve(impl).name
+    backend = bk.resolve(impl, require=(bk.WAVEFRONT,)).name
     if backend in ("scan", "dense") or _series_d(x) > 1:
         return ref.dtw_band_batch(x, y, radius)
     return banded_dtw(x, y, radius, interpret=not bk.on_tpu())
@@ -139,7 +141,7 @@ def _log_krdtw_pairs(x: jnp.ndarray, y: jnp.ndarray, nu: float,
                      radius: Optional[int] = None,
                      support: Optional[jnp.ndarray] = None,
                      impl: str = "auto") -> jnp.ndarray:
-    backend = bk.resolve(impl).name
+    backend = bk.resolve(impl, require=(bk.WAVEFRONT,)).name
     # the anti-diagonal wavefront kernel is univariate
     if backend in ("scan", "dense") or _series_d(x) > 1:
         if support is not None:
@@ -266,8 +268,7 @@ def soft_spdtw_pairs(x: jnp.ndarray, y: jnp.ndarray, *,
     A *bsp-only* caller is a serving call: it runs the paired scan on
     the caller's own plan (tile size preserved, no densify/re-sparsify
     round trip; autodiff still works by differentiating through the
-    scan). There is no separate Pallas *paired* soft kernel; the Gram
-    kernels cover the TPU path (``soft_spdtw_gram``).
+    scan). There is no Pallas *paired* soft kernel.
 
     Deprecated as a module-level entry: use ``engine.soft_pairs`` /
     ``engine.grad``.
@@ -285,7 +286,7 @@ def _soft_spdtw_gram(A: jnp.ndarray, B: jnp.ndarray, *,
                      gamma: float = 1.0, impl: str = "auto",
                      tile: Optional[int] = None,
                      block_a: int = 64) -> jnp.ndarray:
-    require = []
+    require = [bk.DIFFERENTIABLE]
     if bsp is None and sp is None and bk.is_traced(weights):
         require.append(bk.TRACED_WEIGHTS)
     backend = bk.resolve(impl, require=tuple(require)).name
@@ -320,11 +321,11 @@ def soft_spdtw_gram(A: jnp.ndarray, B: jnp.ndarray, *,
 
     impl mirrors ``spdtw_gram``: "auto" routes through
     ``soft_block.soft_spdtw_gram_batch`` — custom VJP whose forward is
-    the block-sparse Gram engine (Pallas on TPU, active-tile scan
-    elsewhere) and whose backward is the reverse active-tile sweep over
-    the stashed L blocks (fused Pallas Gram-backward kernel on TPU;
-    DESIGN.md §11). "pallas" forces the forward kernel directly
-    (interpret off TPU; what the tpu-marked parity test sweeps),
+    the block-sparse Gram engine and whose backward is the reverse
+    active-tile sweep over the stashed L blocks (DESIGN.md §11). Soft
+    calls require DIFFERENTIABLE, which the pallas record omits (its
+    soft kernels do not compile for the chip), so "auto" and "pallas"
+    both resolve to the scan engines;
     "scan"/"ref" the forward jnp scan engine, "dense" the nested-vmap
     core recursion (traceable, and the only path for traced weight
     grids; its backward is the dense expected-alignment oracle). A
@@ -368,7 +369,7 @@ def _log_krdtw_gram(A: jnp.ndarray, B: jnp.ndarray, nu: float, *,
                     support: Optional[jnp.ndarray] = None,
                     radius: Optional[int] = None, impl: str = "auto",
                     block_a: int = 64) -> jnp.ndarray:
-    backend = bk.resolve(impl).name
+    backend = bk.resolve(impl, require=(bk.WAVEFRONT,)).name
     if backend in ("scan", "dense") or bk.is_traced(support) or \
             _series_d(A) > 1:
         sup = None if support is None else jnp.asarray(support)

@@ -41,8 +41,9 @@ Backward engines (DESIGN.md §11 — the reverse active-tile sweep):
 Differentiable entries (custom VJPs):
   * ``soft_spdtw_batch``      — batched aligned pairs: block-sparse
                                 stash forward, reverse-sweep backward;
-  * ``soft_spdtw_gram_batch`` — all-pairs Gram: same, with the Pallas
-                                backward on TPU.
+  * ``soft_spdtw_gram_batch`` — all-pairs Gram: same; the backend is
+                                resolved by capability (DIFFERENTIABLE),
+                                which today is scan on every platform.
 
 Gradients are the expected-alignment matrix E contracted with the local
 cost derivatives; E is identically zero outside the learned support, so
@@ -775,7 +776,7 @@ def gram_soft_bwd_scan(A: jnp.ndarray, B: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Fused Pallas kernels (TPU path; tested under the `tpu` marker)
+# Fused Pallas kernels (interpret-mode parity; Mosaic does not lower them yet)
 # ---------------------------------------------------------------------------
 
 def _gather_soft_edges(meta_ref, g, row_edge, col_edge, corner_next, bt, S):
@@ -786,7 +787,7 @@ def _gather_soft_edges(meta_ref, g, row_edge, col_edge, corner_next, bt, S):
     left_ok = meta_ref[g, 4] > 0
     diag_ok = meta_ref[g, 5] > 0
     neg_row = jnp.full((bt, S), NEG, jnp.float32)
-    top_raw = pl.load(row_edge, (slice(None), pl.dslice(tj * S, S)))
+    top_raw = row_edge[:, pl.ds(tj * S, S)]
     top_vec = jnp.where(top_ok, top_raw, neg_row)
     left_vec = jnp.where(left_ok, col_edge[...], neg_row)
     c_first = jnp.where(
@@ -795,10 +796,8 @@ def _gather_soft_edges(meta_ref, g, row_edge, col_edge, corner_next, bt, S):
                   jnp.where(left_ok, corner_next[...],
                             # guarded: only read when diag_ok (=> tj > 0);
                             # clamp keeps the untaken branch in-bounds
-                            pl.load(row_edge,
-                                    (slice(None),
-                                     pl.dslice(jnp.maximum(tj * S - 1, 0),
-                                               1)))),
+                            row_edge[:, pl.ds(jnp.maximum(tj * S - 1, 0),
+                                              1)]),
                   jnp.full((bt, 1), NEG, jnp.float32)))
     return top_vec, left_vec, c_first
 
@@ -820,8 +819,8 @@ def _gram_soft_kernel(meta_ref, a_ref, b_ref, w_ref, out_ref,
     ti = meta_ref[g, 0]
     tj = meta_ref[g, 1]
     # tile-major layout: tile ti's d channel planes are contiguous
-    xa = pl.load(a_ref, (slice(None), pl.dslice(ti * d * S, d * S)))
-    yb = pl.load(b_ref, (slice(None), pl.dslice(tj * d * S, d * S)))
+    xa = a_ref[:, pl.ds(ti * d * S, d * S)]
+    yb = b_ref[:, pl.ds(tj * d * S, d * S)]
     x, y = _pair_batch(xa, yb, ba, bb)                         # (bt, d*S)
     w = w_ref[0]                                               # (S, S)
 
@@ -834,7 +833,7 @@ def _gram_soft_kernel(meta_ref, a_ref, b_ref, w_ref, out_ref,
                                             d=d)
 
     corner_next[...] = new_corner
-    pl.store(row_edge, (slice(None), pl.dslice(tj * S, S)), d_last)
+    row_edge[:, pl.ds(tj * S, S)] = d_last
     col_edge[...] = rightcol
     d_ri[...] = dri
 
@@ -929,8 +928,8 @@ def _gram_soft_stash_kernel(meta_ref, a_ref, b_ref, w_ref,
 
     ti = meta_ref[g, 0]
     tj = meta_ref[g, 1]
-    xa = pl.load(a_ref, (slice(None), pl.dslice(ti * S, S)))
-    yb = pl.load(b_ref, (slice(None), pl.dslice(tj * S, S)))
+    xa = a_ref[:, pl.ds(ti * S, S)]
+    yb = b_ref[:, pl.ds(tj * S, S)]
     x, y = _pair_batch(xa, yb, ba, bb)
     w = w_ref[0]
 
@@ -942,7 +941,7 @@ def _gram_soft_stash_kernel(meta_ref, a_ref, b_ref, w_ref,
         x, y, w, top_vec, left_vec, c_first, S=S, ri=ri, gamma=gamma)
 
     corner_next[...] = new_corner
-    pl.store(row_edge, (slice(None), pl.dslice(tj * S, S)), d_last)
+    row_edge[:, pl.ds(tj * S, S)] = d_last
     col_edge[...] = rightcol
     d_ri[...] = dri
     lstash_ref[0, 0, 0] = Lblk.reshape(bt * S, S)
@@ -1041,30 +1040,24 @@ def _gram_soft_bwd_kernel(rmeta_ref, a_ref, b_ref, w_ref, lstash_ref,
     right_ok = rmeta_ref[k, 4] > 0
     diag_ok = rmeta_ref[k, 5] > 0
 
-    xa = pl.load(a_ref, (slice(None), pl.dslice(ti * S, S)))
-    yb = pl.load(b_ref, (slice(None), pl.dslice(tj * S, S)))
+    xa = a_ref[:, pl.ds(ti * S, S)]
+    yb = b_ref[:, pl.ds(tj * S, S)]
     x, y = _pair_batch(xa, yb, ba, bb)
     w = w_ref[0]
     Lblk = lstash_ref[0, 0, 0].reshape(bt, S * S)
 
     zero_row = jnp.zeros((bt, S), jnp.float32)
     neg_row = jnp.full((bt, S), NEG, jnp.float32)
-    bE = jnp.where(below_ok,
-                   pl.load(topE, (slice(None), pl.dslice(tj * S, S))),
-                   zero_row)
-    bL = jnp.where(below_ok,
-                   pl.load(topL, (slice(None), pl.dslice(tj * S, S))),
-                   neg_row)
-    bt_ = jnp.where(below_ok,
-                    pl.load(topt, (slice(None), pl.dslice(tj * S, S))),
-                    neg_row)
+    bE = jnp.where(below_ok, topE[:, pl.ds(tj * S, S)], zero_row)
+    bL = jnp.where(below_ok, topL[:, pl.ds(tj * S, S)], neg_row)
+    bt_ = jnp.where(below_ok, topt[:, pl.ds(tj * S, S)], neg_row)
     rE = jnp.where(right_ok, colE[...], zero_row)
     rL = jnp.where(right_ok, colL[...], neg_row)
     rt = jnp.where(right_ok, colt[...], neg_row)
     dcol = jnp.minimum((tj + 1) * S, Tp - 1)
-    dEr = pl.load(topE, (slice(None), pl.dslice(dcol, 1)))
-    dLr = pl.load(topL, (slice(None), pl.dslice(dcol, 1)))
-    dtr = pl.load(topt, (slice(None), pl.dslice(dcol, 1)))
+    dEr = topE[:, pl.ds(dcol, 1)]
+    dLr = topL[:, pl.ds(dcol, 1)]
+    dtr = topt[:, pl.ds(dcol, 1)]
     cE = jnp.where(diag_ok, jnp.where(right_ok, corE[...], dEr),
                    jnp.zeros((bt, 1), jnp.float32))
     cL = jnp.where(diag_ok, jnp.where(right_ok, corL[...], dLr),
@@ -1081,10 +1074,9 @@ def _gram_soft_bwd_kernel(rmeta_ref, a_ref, b_ref, w_ref, lstash_ref,
     E3 = Eblk.reshape(bt, S, S)
     L3 = Lblk.reshape(bt, S, S)
 
-    pl.store(topE, (slice(None), pl.dslice(tj * S, S)), E3[:, 0, :])
-    pl.store(topL, (slice(None), pl.dslice(tj * S, S)), L3[:, 0, :])
-    pl.store(topt, (slice(None), pl.dslice(tj * S, S)),
-             _row0_logits(x, y, w, gamma))
+    topE[:, pl.ds(tj * S, S)] = E3[:, 0, :]
+    topL[:, pl.ds(tj * S, S)] = L3[:, 0, :]
+    topt[:, pl.ds(tj * S, S)] = _row0_logits(x, y, w, gamma)
     colE[...] = E3[:, :, 0]
     colL[...] = L3[:, :, 0]
     colt[...] = _col0_logits(x, y, w, gamma)
@@ -1098,13 +1090,11 @@ def _gram_soft_bwd_kernel(rmeta_ref, a_ref, b_ref, w_ref, lstash_ref,
     gy_t = -2.0 * ((Ew * x[:, :, None]).sum(1) - y * Ew.sum(1)) * gbar
     phi3 = (x[:, :, None] - y[:, None, :]) ** 2
     gw_ref[0, 0, 0] = (E3 * phi3 * gbar[:, :, None]).sum(0)
-    ga_cur = pl.load(ga_ref, (slice(None), pl.dslice(ti * S, S)))
-    pl.store(ga_ref, (slice(None), pl.dslice(ti * S, S)),
-             ga_cur + gx_t.reshape(ba, bb, S).sum(1))
-    gb_cur = pl.load(gb_ref,
-                     (slice(None), slice(None), pl.dslice(tj * S, S)))
-    pl.store(gb_ref, (slice(None), slice(None), pl.dslice(tj * S, S)),
-             gb_cur + gy_t.reshape(ba, bb, S).sum(0)[None])
+    ga_cur = ga_ref[:, pl.ds(ti * S, S)]
+    ga_ref[:, pl.ds(ti * S, S)] = ga_cur + gx_t.reshape(ba, bb, S).sum(1)
+    gb_cur = gb_ref[:, :, pl.ds(tj * S, S)]
+    gb_ref[:, :, pl.ds(tj * S, S)] = (gb_cur
+                                      + gy_t.reshape(ba, bb, S).sum(0)[None])
 
 
 @functools.partial(jax.jit,
@@ -1334,13 +1324,13 @@ def soft_spdtw_gram_batch(A: jnp.ndarray, B: jnp.ndarray,
     """All-pairs soft-SP-DTW Gram matrix, differentiable in A, B, weights.
 
     A: (Na, T) or (Na, T, d); B likewise; weights: (T, T). Returns
-    (Na, Nb). Forward
-    runs the block-sparse Gram engine (Pallas on TPU, active-tile scan
-    elsewhere) when ``weights`` is host-concrete; the backward is the
-    reverse active-tile sweep over the stashed L blocks — the fused
-    Pallas Gram-backward kernel on TPU, the lax.scan reverse engine
-    elsewhere (DESIGN.md §11). Traced weight grids fall back to the
-    nested-vmap dense recursion and its dense backward.
+    (Na, Nb). Forward runs the block-sparse Gram engine when
+    ``weights`` is host-concrete; the backward is the reverse active-tile
+    sweep over the stashed L blocks (DESIGN.md §11). Both passes take
+    the backend that ``resolve`` gives for DIFFERENTIABLE: the scan
+    engines, since the soft Pallas kernels do not compile for the chip
+    and the pallas record omits that capability. Traced weight grids
+    fall back to the nested-vmap dense recursion and its dense backward.
     """
     return _soft_gram_value(A, B, weights, gamma)
 

@@ -63,6 +63,15 @@ def _minplus_scan_lanes(u, c, width):
     return m
 
 
+def _pick(v, idx, iota, axis):
+    """Slice ``idx`` of ``v`` along ``axis`` (kept as a size-1 axis) for a
+    traced ``idx``: a select against ``iota`` and a min-reduce, which is
+    exact (every other entry reads +inf) and, unlike ``dynamic_slice`` on a
+    value, lowers in Mosaic as well as in XLA."""
+    return jnp.min(jnp.where(iota == idx, v, jnp.inf), axis=axis,
+                   keepdims=True)
+
+
 def tile_cost_row(x, y, w, t, *, S: int, d: int = 1):
     """Weighted local-cost row ``t`` of one tile for a pair batch.
 
@@ -72,14 +81,16 @@ def tile_cost_row(x, y, w, t, *, S: int, d: int = 1):
     channels before the weight multiply, so the multivariate DP is the
     *dependent* DTW of the summed local cost under one shared path —
     exactly what the dense core DPs (``core.dtw.local_cost``) compute.
-    Masked cells (w == 0) read +INF. Shared by the hard sweeps here / in
-    ``gram_block``; the soft twin lives in ``soft_block``.
+    Masked cells (w == 0) read +INF. ``t`` may be traced (the row loop
+    index). Shared by the hard sweeps here / in ``gram_block``; the soft
+    twin lives in ``soft_block``.
     """
-    wt = jax.lax.dynamic_slice_in_dim(w, t, 1, axis=0)          # (1,S)
+    wt = _pick(w, t, jax.lax.broadcasted_iota(jnp.int32, w.shape, 0), 0)
+    xlane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     acc = None
     for k in range(d):
-        xt = jax.lax.dynamic_slice_in_dim(x, k * S + t, 1, axis=1)
-        yk = jax.lax.dynamic_slice_in_dim(y, k * S, S, axis=1)
+        xt = _pick(x, k * S + t, xlane, 1)                       # (bt,1)
+        yk = y[:, k * S:(k + 1) * S]
         dk = (xt - yk) ** 2
         acc = dk if acc is None else acc + dk
     return jnp.where(wt > 0, acc * wt, INF)
@@ -91,7 +102,10 @@ def tile_sweep(x, y, w, top_vec, left_vec, c_first, *, S: int, ri: int,
 
     Pure jnp on values (no refs), so it is shared verbatim by the single-pair
     Pallas kernel here, the fused Gram kernel in ``gram_block.py`` and the
-    jnp scan engine (same math => parity by construction).
+    jnp scan engine (same math => parity by construction). Row- and
+    column-indexed reads and writes at the loop index go through lane
+    selects (``_pick`` / ``jnp.where``), never a value-level dynamic slice,
+    so the one body lowers both in XLA and in Mosaic.
 
     x, y:      (bt, d*S) per-pair series tiles, tile-major / channel-inner
                (rows of x, cols of y; d = 1 is the historical (bt, S)).
@@ -111,6 +125,7 @@ def tile_sweep(x, y, w, top_vec, left_vec, c_first, *, S: int, ri: int,
     and the row at in-tile index ``ri`` (global result-row capture).
     """
     bt = x.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, S), 1)
 
     def cost_row(t):
         return tile_cost_row(x, y, w, t, S=S, d=d)
@@ -131,16 +146,14 @@ def tile_sweep(x, y, w, top_vec, left_vec, c_first, *, S: int, ri: int,
 
     def body(t, carry):
         d_prev, rightcol, dri = carry
-        tl0 = jax.lax.dynamic_slice_in_dim(left_vec, t - 1, 1, axis=1)
-        lt = jax.lax.dynamic_slice_in_dim(left_vec, t, 1, axis=1)
+        tl0 = _pick(left_vec, t - 1, lane, 1)
+        lt = _pick(left_vec, t, lane, 1)
         d_row = row_update(t, d_prev, tl0, lt)
-        rightcol = jax.lax.dynamic_update_slice(
-            rightcol, d_row[:, S - 1:S], (0, t))
+        rightcol = jnp.where(lane == t, d_row[:, S - 1:S], rightcol)
         dri = jnp.where(t == ri, d_row, dri)
         return d_row, rightcol, dri
 
-    rightcol0 = jnp.full((bt, S), INF, jnp.float32)
-    rightcol0 = jax.lax.dynamic_update_slice(rightcol0, d0[:, S - 1:S], (0, 0))
+    rightcol0 = jnp.where(lane == 0, d0[:, S - 1:S], INF)
     dri0 = jnp.where(ri == 0, d0, jnp.full((bt, S), INF, jnp.float32))
     return jax.lax.fori_loop(1, S, body, (d0, rightcol0, dri0))
 
@@ -148,22 +161,25 @@ def tile_sweep(x, y, w, top_vec, left_vec, c_first, *, S: int, ri: int,
 def _spdtw_block_kernel(meta_ref, x_ref, y_ref, w_ref, out_ref,
                         row_edge, col_edge, corner_next, d_ri,
                         *, S: int, g_out: int, ri: int, rj: int, d: int):
-    """One grid step = one active tile (meta columns: ti,tj,slot,top,left,diag)."""
+    """One grid step = one active tile (meta columns: ti,tj,slot,top,left,diag).
+
+    ``row_edge`` is (Ti, bt, S): tile column tj's bottom edge is indexed on
+    the leading axis, so no access ever needs a dynamic lane offset (Mosaic
+    only proves 128-aligned ones)."""
     g = pl.program_id(1)
-    bt = x_ref.shape[0]
+    bt = x_ref.shape[1]
     tj = meta_ref[g, 1]
     top_ok = meta_ref[g, 3] > 0
     left_ok = meta_ref[g, 4] > 0
     diag_ok = meta_ref[g, 5] > 0
 
-    x = x_ref[...]                  # (bt, d*S) rows of this tile
-    y = y_ref[...]                  # (bt, d*S) cols of this tile
+    x = x_ref[0]                    # (bt, d*S) rows of this tile
+    y = y_ref[0]                    # (bt, d*S) cols of this tile
     w = w_ref[0]                    # (S, S) weight block
 
     # --- gather incoming edges (guarded against inactive neighbours) ---
     inf_row = jnp.full((bt, S), INF, jnp.float32)
-    top_raw = pl.load(row_edge, (slice(None), pl.dslice(tj * S, S)))
-    top_vec = jnp.where(top_ok, top_raw, inf_row)
+    top_vec = jnp.where(top_ok, row_edge[tj], inf_row)
     left_vec = jnp.where(left_ok, col_edge[...], inf_row)
     c_first = jnp.where(
         g == 0, jnp.zeros((bt, 1), jnp.float32),
@@ -171,9 +187,7 @@ def _spdtw_block_kernel(meta_ref, x_ref, y_ref, w_ref, out_ref,
                   jnp.where(left_ok, corner_next[...],
                             # guarded: only read when diag_ok (=> tj > 0);
                             # clamp keeps the untaken branch in-bounds
-                            pl.load(row_edge,
-                                    (slice(None),
-                                     pl.dslice(jnp.maximum(tj * S - 1, 0), 1)))),
+                            row_edge[jnp.maximum(tj - 1, 0)][:, S - 1:S]),
                   jnp.full((bt, 1), INF, jnp.float32)))
 
     # corner for the *next* tile (i, j+1) = last element of this tile's top row
@@ -184,7 +198,7 @@ def _spdtw_block_kernel(meta_ref, x_ref, y_ref, w_ref, out_ref,
 
     # --- publish edges for downstream tiles ---
     corner_next[...] = new_corner
-    pl.store(row_edge, (slice(None), pl.dslice(tj * S, S)), d_last)
+    row_edge[tj] = d_last
     col_edge[...] = rightcol
     d_ri[...] = dri
 
@@ -193,7 +207,7 @@ def _spdtw_block_kernel(meta_ref, x_ref, y_ref, w_ref, out_ref,
     # for raw user weights — none at the corner at all)
     @pl.when(g == g_out)
     def _():
-        out_ref[...] = jax.lax.dynamic_slice_in_dim(dri, rj, 1, axis=1)
+        out_ref[...] = d_ri[:, rj:rj + 1]
 
 
 def _host_plan(bsp: BlockSparsePaths) -> Tuple[np.ndarray, int]:
@@ -217,8 +231,7 @@ def result_tile_step(meta: np.ndarray, S: int, T_orig: int) -> int:
                                     "block_b", "d", "interpret"))
 def _spdtw_block_call(meta, x, y, blocks, *, S, n_active, T_orig, g_out,
                       block_b, d, interpret):
-    Bp = x.shape[0]
-    Tp = (x.shape[1] // d // S) * S          # DP grid edge (padded)
+    Ti, Bp, _ = x.shape             # tile-stacked: (Ti, Bp, d*S)
     last = T_orig - 1
     ri, rj = last % S, last % S
     grid = (Bp // block_b, n_active)
@@ -228,18 +241,18 @@ def _spdtw_block_call(meta, x, y, blocks, *, S, n_active, T_orig, g_out,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            # tile-major layout: block column ti covers the d channel
-            # planes of tile ti, so per-tile indexing is unchanged
-            pl.BlockSpec((block_b, d * S), lambda b, g, m: (b, m[g, 0])),
-            pl.BlockSpec((block_b, d * S), lambda b, g, m: (b, m[g, 1])),
+            # tile ti of every series is one leading-axis slab, so the
+            # block's lane extent is the whole d*S (always lane-legal)
+            pl.BlockSpec((1, block_b, d * S), lambda b, g, m: (m[g, 0], b, 0)),
+            pl.BlockSpec((1, block_b, d * S), lambda b, g, m: (m[g, 1], b, 0)),
             pl.BlockSpec((1, S, S), lambda b, g, m: (m[g, 2], 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, 1), lambda b, g, m: (b, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_b, Tp), jnp.float32),   # row_edge
-            pltpu.VMEM((block_b, S), jnp.float32),    # col_edge
-            pltpu.VMEM((block_b, 1), jnp.float32),    # corner_next
-            pltpu.VMEM((block_b, S), jnp.float32),    # d_ri capture
+            pltpu.VMEM((Ti, block_b, S), jnp.float32),  # row_edge
+            pltpu.VMEM((block_b, S), jnp.float32),      # col_edge
+            pltpu.VMEM((block_b, 1), jnp.float32),      # corner_next
+            pltpu.VMEM((block_b, S), jnp.float32),      # d_ri capture
         ],
     )
     return pl.pallas_call(
@@ -257,7 +270,7 @@ def spdtw_block(x: jnp.ndarray, y: jnp.ndarray, bsp: BlockSparsePaths,
     x, y: (B, T_orig) or (B, T_orig, d) f32. Returns (B,) SP-DTW values
     (INF-like where the support admits no path).
     """
-    from .backends import series_dim, to_tile_major
+    from .backends import series_dim, to_tile_stack
     B, T = x.shape[0], x.shape[1]
     d = series_dim(x)
     T_orig = T if T_orig is None else T_orig
@@ -268,8 +281,8 @@ def spdtw_block(x: jnp.ndarray, y: jnp.ndarray, bsp: BlockSparsePaths,
         return jnp.full((B,), INF, jnp.float32)
     Bp = ((B + block_b - 1) // block_b) * block_b
     out = _spdtw_block_call(
-        jnp.asarray(meta), to_tile_major(x, bsp.tile, bsp.T, n_to=Bp),
-        to_tile_major(y, bsp.tile, bsp.T, n_to=Bp), jnp.asarray(bsp.blocks),
+        jnp.asarray(meta), to_tile_stack(x, bsp.tile, bsp.T, n_to=Bp),
+        to_tile_stack(y, bsp.tile, bsp.T, n_to=Bp), jnp.asarray(bsp.blocks),
         S=bsp.tile, n_active=n_active, T_orig=T_orig, g_out=g_out,
         block_b=block_b, d=d, interpret=interpret)
     return out[:B, 0]

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.dtw import band_mask
 
 
@@ -52,7 +51,7 @@ def cluster_job(mesh, weights, gamma: float = 0.1, *, steps: int = 30,
 
         return jax.vmap(fit_one)(Z0, A)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(axes, None)),
         out_specs=(P(axes, None), P(axes)),
@@ -68,7 +67,7 @@ def run(k: int = 8, n: int = 64, t: int = 64, gamma: float = 0.1,
     n_dev = mesh.size
     k = ((k + n_dev - 1) // n_dev) * n_dev   # pad centroids to device count
     w = np.asarray(band_mask(t, t, max(t // 8, 1)), np.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         job = cluster_job(mesh, w, gamma, steps=steps)
         if dryrun:
             Z0 = jax.ShapeDtypeStruct((k, t), jnp.float32)

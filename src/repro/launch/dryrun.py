@@ -35,7 +35,6 @@ import traceback       # noqa: E402
 import jax             # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro import compat                                # noqa: E402
 from repro.configs import ARCH_IDS, get_config          # noqa: E402
 from repro.launch import hlo_analysis                   # noqa: E402
 from repro.launch.mesh import make_production_mesh      # noqa: E402
@@ -220,7 +219,7 @@ def dryrun_cell(arch: str, shape: str, multi_pod: bool,
         return {"arch": arch, "shape": shape, "mesh": mesh_name,
                 "status": "skipped", "reason": why}
     mesh = make_production_mesh(multi_pod=multi_pod)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         compiled, cell = compile_real_step(cfg, shape, mesh)
         t_compile = time.time() - t0

@@ -32,7 +32,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.dtw import band_mask
 from repro.core.engine import engine_for
-from repro import compat
 
 
 def gram_job(mesh, weights, kind: str = "spdtw", nu: float = 1.0,
@@ -56,7 +55,7 @@ def gram_job(mesh, weights, kind: str = "spdtw", nu: float = 1.0,
             return eng.gram_log(xs, ys, impl=impl, block_a=xs.shape[0])
         return eng.gram(xs, ys, impl=impl, block_a=xs.shape[0])
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=P(axes, None),
@@ -92,7 +91,7 @@ def knn_job(mesh, weights, kind: str = "spdtw", impl: str = "auto",
                            prefix_frac=prefix_frac)
         return nn, dist
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=(P(axes), P(axes)),
@@ -108,7 +107,7 @@ def run(n: int = 64, t: int = 64, kind: str = "spdtw",
     n_dev = mesh.size
     n = ((n + n_dev - 1) // n_dev) * n_dev   # pad rows to device count
     w = np.asarray(band_mask(t, t, max(t // 8, 1)), np.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if mode == "knn":
             job = knn_job(mesh, w, kind=kind)
         else:

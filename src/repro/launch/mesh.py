@@ -10,13 +10,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-agnostic mesh: jax >= 0.5 takes axis_types, 0.4.x does not."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """Named mesh with every axis ``Auto`` (sharding propagated by XLA)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

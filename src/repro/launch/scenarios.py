@@ -70,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import learn_sparse_paths
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.search import SearchEngine, _make_workload
 from repro.launch.stats import percentiles
 
@@ -495,6 +496,7 @@ def main(argv=None):
                     help="artifact directory (default: repo root, or a "
                          "fresh tempdir with --smoke)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     refresh = args.scenario == "server+refresh"
     anomaly = args.scenario == "anomaly"
     if anomaly:

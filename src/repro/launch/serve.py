@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 
 from repro.configs import get_config, reduced
 from repro.launch.mesh import make_host_mesh
@@ -32,7 +31,7 @@ def serve(arch: str, batch: int = 4, prompt_len: int = 16,
     mesh = make_host_mesh(1, 1)
     S_cache = prompt_len + gen_tokens
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init_params(jax.random.PRNGKey(seed))
         rng = np.random.default_rng(seed)
         batch_inputs = {"tokens": jnp.asarray(

@@ -56,7 +56,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.engine import SimilarityEngine
 from repro.kernels import backends as bk
 
@@ -244,7 +243,7 @@ def sharded_knn_job(engine: SimilarityEngine, mesh, *, axis: str = "shard",
         gids = jnp.moveaxis(all_g, 0, 1).reshape(B, -1)
         return merge_topk(dists, gids, k)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(), P()),

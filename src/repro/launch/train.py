@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 
 from repro.configs import get_config, reduced
 from repro.launch.mesh import make_host_mesh
@@ -49,7 +48,7 @@ def train(arch: str, steps: int = 20, use_reduced: bool = True,
     opt = AdamW(lr=cosine_schedule(lr, max(steps // 10, 1), steps))
     step_fn = make_train_step(api, mesh, opt, microbatch=microbatch)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pspecs = api.param_pspecs()
         param_sh = specs_to_shardings(pspecs, mesh)
         params = jax.device_put(api.init_params(jax.random.PRNGKey(seed)),

@@ -24,7 +24,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -292,7 +291,7 @@ def _apply_attn(x, p, spec: LayerSpec, cfg: ModelConfig, ctx: Ctx,
     out = jnp.einsum("bshk,hkd->bsd", o.astype(x.dtype), p["wo"])
     # pin the bf16 convert *before* the TP psum: otherwise XLA reduces the
     # f32 dot accumulator over the wire (2x collective volume, §Perf H2)
-    out = compat.optimization_barrier(out.astype(x.dtype))
+    out = jax.lax.optimization_barrier(out.astype(x.dtype))
     # name the TP-boundary output so the save_tp remat policy can keep it
     # (the rematerialized forward then skips this psum entirely, §Perf H2)
     out = checkpoint_name(out, "tp_out")
@@ -367,7 +366,7 @@ def _apply_ffn(x, p, spec: LayerSpec, cfg: ModelConfig, ctx: Ctx):
     xn = rms_norm(x, p["norm2"], cfg.norm_eps)
     if spec.ffn == "mlp":
         h = jax.nn.silu(xn @ p["w_gate"]) * (xn @ p["w_up"])
-        out = compat.optimization_barrier((h @ p["w_down"]).astype(x.dtype))
+        out = jax.lax.optimization_barrier((h @ p["w_down"]).astype(x.dtype))
         out = checkpoint_name(out, "tp_out")
         return x + out, jnp.float32(0)
     # MoE

@@ -27,7 +27,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 
@@ -137,7 +136,7 @@ def moe_ffn(x: jnp.ndarray, params: dict, *, n_experts: int, top_k: int,
     # jax.checkpoint INSIDE the shard_map body: the outer scan-level remat
     # does not reach through shard_map, so without this every group's
     # dispatch/gather buffers (~.25 GB each) survive to the backward pass
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         jax.checkpoint(
             lambda xl, r, wg, wu, wd: run(xl, r, wg, wu, wd, n_data,
                                           n_data)),

@@ -19,7 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import Ctx
@@ -130,7 +129,7 @@ def make_train_step(api, mesh, opt: AdamW, *, microbatch: int = 1,
             loss = jax.lax.pmean(ltot / microbatch, dp)
             return loss, g
 
-        return compat.shard_map(
+        return jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),
                       jax.tree.map(lambda _: P(ctx.dp), batch)),
@@ -156,7 +155,7 @@ def make_train_step(api, mesh, opt: AdamW, *, microbatch: int = 1,
 
             pspecs = api.param_pspecs()
             from repro.launch.shapes import specs_to_shardings  # noqa
-            loss, grads = compat.shard_map(
+            loss, grads = jax.shard_map(
                 pod_grads, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), params),
                           jax.tree.map(lambda _: P("pod"), batch)),

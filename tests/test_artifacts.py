@@ -140,14 +140,14 @@ def test_ci_workflow_encodes_the_gate():
     assert "python -m benchmarks.check_artifacts" in text
     assert "timeout-minutes" in text
     assert "cache: pip" in text
-    # ISSUE 8 serving + compat gates: the simulated 4-way mesh smoke,
-    # the serving-artifact schema check, the jax pin matrix and the
-    # 14-day artifact upload must all stay wired
+    # serving gates: the simulated 4-way mesh smoke, the serving-artifact
+    # schema check, the one supported jax pin and the 14-day artifact
+    # upload must all stay wired
     assert "repro.launch.scenarios --smoke" in text
     assert "--xla_force_host_platform_device_count=4" in text
     assert "actions/upload-artifact@v4" in text
     assert "retention-days: 14" in text
-    assert "0.4.30" in text and "tests/test_compat.py" in text
+    assert '"jax[cpu]==0.9.0"' in text and "0.4." not in text
     # ISSUE 10 monitor gate: the anomaly scenario smoke must stay wired
     assert "--scenario anomaly" in text
 

@@ -220,7 +220,6 @@ def test_cluster_job_matches_unsharded():
     from repro.launch import cluster as lc
     from repro.launch.mesh import make_host_mesh
     from repro.core.dtw import band_mask
-    from repro import compat
     t, n, k = 16, 12, 2
     rng = np.random.default_rng(3)
     X = jnp.asarray(rng.normal(size=(n, t)).astype(np.float32))
@@ -229,7 +228,7 @@ def test_cluster_job_matches_unsharded():
                     .astype(np.float32))
     Z0 = jnp.asarray(rng.normal(size=(k, t)).astype(np.float32))
     mesh = make_host_mesh(1, 1)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         job = lc.cluster_job(mesh, w, 0.1, steps=6)
         Zs, _ = job(Z0, X, A)
     Zd = []

@@ -63,7 +63,7 @@ def _oracle(A, B, weights):
 
 
 # --------------------------------------------------------- banded LB_Kim
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.floats(0.2, 0.7), st.integers(0, 10 ** 6),
        st.sampled_from([None, 2]))
 def test_banded_kim_admissible(density, seed, d):
@@ -113,7 +113,7 @@ def test_multivariate_keogh_admissible():
 
 
 # ---------------------------------------------------- log-semiring bound
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.floats(0.3, 2.0), st.sampled_from(["krdtw", "sp_krdtw"]),
        st.integers(0, 10 ** 6))
 def test_krdtw_bound_admissible(nu, kind, seed):
@@ -185,7 +185,7 @@ def test_indp_prune_exact_or_inf(engine, d):
     assert np.array_equal(got.min(axis=1), base.min(axis=1))
 
 
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(st.integers(0, 10 ** 6))
 def test_indp_live_tiles_monotone(seed):
     """The live-tile counter equals the static support at +INF thresholds

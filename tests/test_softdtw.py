@@ -90,8 +90,7 @@ def test_infeasible_support_is_inf_with_zero_grads():
 # ------------------------------------------------- VJP vs finite differences
 def _fd_check(w, gamma, T, seed, rtol=1e-3):
     """Central finite differences in f64 against the custom VJP."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(seed)
         x = jnp.asarray(rng.normal(size=T))
         y = jnp.asarray(rng.normal(size=T))

@@ -14,7 +14,6 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import SparsePaths, block_sparsify, learn_sparse_paths
 from repro.core.softdtw import soft_alignment, soft_wdtw
@@ -65,7 +64,7 @@ def test_e_matrix_parity_f64(maker, tile):
     bsp = block_sparsify(sp, tile=tile)
     rng = np.random.default_rng(5)
     xs, ys = rng.normal(size=(4, T)), rng.normal(size=(4, T))
-    with enable_x64():
+    with jax.enable_x64(True):
         x, y = jnp.asarray(xs), jnp.asarray(ys)
         w = jnp.asarray(np.asarray(sp.weights, np.float64))
         for gamma in (0.5, 0.1):
@@ -84,7 +83,7 @@ def test_e_matrix_parity_f32():
     bsp = block_sparsify(sp, tile=8)
     rng = np.random.default_rng(7)
     xs, ys = rng.normal(size=(3, T)), rng.normal(size=(3, T))
-    with enable_x64():
+    with jax.enable_x64(True):
         Ed = _dense_E(jnp.asarray(xs), jnp.asarray(ys),
                       jnp.asarray(np.asarray(sp.weights, np.float64)), 0.3)
     Eb = np.asarray(soft_alignment_pairs(

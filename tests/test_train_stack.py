@@ -139,8 +139,7 @@ def test_int8_psum_compression_accuracy():
         out = _int8_psum({"g": x}, "pod")
         return out["g"]
 
-    from repro import compat
-    res = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("pod"),
+    res = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
                                    out_specs=P("pod"),
                                    check_vma=False))(g)
     want = np.sum(np.asarray(g), axis=0)
