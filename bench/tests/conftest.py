@@ -1,0 +1,7 @@
+"""CPU tests of the benchmark: ``python -m pytest bench/tests -q``."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
