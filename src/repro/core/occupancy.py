@@ -22,15 +22,15 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .dtw import INF
-from .paths import optimal_path_mask, path_is_feasible
+from .paths import optimal_path_masks64, path_is_feasible
 
 
-def pairwise_path_counts(X: jnp.ndarray, batch_pairs: int = 256) -> jnp.ndarray:
+def pairwise_path_counts(X: jnp.ndarray,
+                         batch_cells: int = 1 << 23) -> jnp.ndarray:
     """Absolute occupancy counts over all N(N-1)/2 training pairs.
 
     X: (N, T) or (N, T, d). Returns float32 (T, T) counts. Each unordered
@@ -39,21 +39,18 @@ def pairwise_path_counts(X: jnp.ndarray, batch_pairs: int = 256) -> jnp.ndarray:
     alignment (in either orientation) visits it — at most N(N-1)/2. (The
     earlier ``counts + counts.T`` post-hoc symmetrization double-counted
     cells lying on both a path and its transpose, e.g. the corners.)
-    Pairs are processed in vmapped chunks to bound memory.
+    Paths come from float64 costs on the host (``optimal_path_masks64``),
+    in chunks of about ``batch_cells`` grid cells to bound memory.
     """
-    N = X.shape[0]
-    T = X.shape[1]
+    X = np.asarray(X)
+    N, T = X.shape[:2]
     iu, ju = np.triu_indices(N, k=1)
-    counts = jnp.zeros((T, T), jnp.float32)
-
-    masked = jax.jit(jax.vmap(
-        lambda a, b: (lambda m: m | m.T)(optimal_path_mask(a, b))))
-    for s in range(0, len(iu), batch_pairs):
-        ii = jnp.asarray(iu[s:s + batch_pairs])
-        jj = jnp.asarray(ju[s:s + batch_pairs])
-        m = masked(X[ii], X[jj])
-        counts = counts + jnp.sum(m.astype(jnp.float32), axis=0)
-    return counts
+    counts = np.zeros((T, T), np.int64)
+    step = max(1, batch_cells // (T + 1) ** 2)
+    for s in range(0, len(iu), step):
+        m = optimal_path_masks64(X[iu[s:s + step]], X[ju[s:s + step]])
+        counts += (m | m.transpose(0, 2, 1)).sum(axis=0)
+    return jnp.asarray(counts, jnp.float32)
 
 
 def normalize_grid(counts: jnp.ndarray) -> jnp.ndarray:

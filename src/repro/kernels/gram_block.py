@@ -97,6 +97,10 @@ def _pair_batch(xa: jnp.ndarray, yb: jnp.ndarray, ba: int, bb: int):
 # SP-DTW: (A-tile, B-tile, active-tile) fused Pallas kernel
 # ---------------------------------------------------------------------------
 
+PAIR_BLOCK = (8, 128)    # the Gram kernel's (A rows, B rows) pair block
+_CNT_TILE = (8, 128)     # the smallest lane-legal float32 output block
+
+
 def _pair_column(m: jnp.ndarray) -> jnp.ndarray:
     """(ba, bb) per-pair block -> (ba*bb, 1) column, pair p = ia*bb + ib.
 
@@ -107,10 +111,13 @@ def _pair_column(m: jnp.ndarray) -> jnp.ndarray:
 
 
 def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
-                       out_ref, row_edge, col_edge, corner_next, d_ri, alive,
-                       *, S: int, g_out: int, ri: int, rj: int,
+                       out_ref, cnt_ref, row_edge, col_edge, corner_next, d_ri,
+                       alive, *, S: int, g_out: int, ri: int, rj: int,
                        ba: int, bb: int, d: int, prune: bool):
-    """One grid step = one active tile for one (A-stripe, B-stripe) block."""
+    """One grid step = one active tile for one (A-stripe, B-stripe) block.
+
+    ``cnt_ref`` is the block's (8, 128) counter tile: [0, 0] counts the
+    tile sweeps run, [0, 1] sums the live pairs over those sweeps."""
     g = pl.program_id(2)
     bt = ba * bb
 
@@ -122,6 +129,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
         # bound-stage survivors (all-ones when no cascade is running)
         row_edge[...] = jnp.full(row_edge.shape, INF, jnp.float32)
         alive[...] = _pair_column(alive0_ref[...])
+        cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.float32)
 
     # early-abandon check at the first tile of each new tile row: the
     # previous tile row is complete, so the running row-min is an
@@ -164,10 +172,20 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
         edge_live = ((jnp.min(top_vec, axis=1, keepdims=True) <= thr_p)
                      | (jnp.min(left_vec, axis=1, keepdims=True) <= thr_p)
                      | (c_first <= thr_p))
-        do_sweep = jnp.any((alive[...] > 0) & edge_live)
+        live = alive[...] * edge_live.astype(jnp.float32)         # (bt, 1)
     else:
         # the whole tile sweep is skipped once every pair is dead
-        do_sweep = jnp.any(alive[...] > 0)
+        live = alive[...]
+    do_sweep = jnp.any(live > 0)
+
+    # counters: a sweep runs iff some pair is live, so the block's live
+    # count is its contribution to the swept pairs, and 0 when skipped
+    n_live = jnp.sum(live, axis=0, keepdims=True)                 # (1, 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 1)
+    cnt_ref[...] += jnp.where(
+        sub == 0, jnp.where(lane == 0, jnp.minimum(n_live, 1.0),
+                            jnp.where(lane == 1, n_live, 0.0)), 0.0)
 
     @pl.when(do_sweep)
     def _():
@@ -215,6 +233,8 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
                                     "ba", "bb", "d", "prune", "interpret"))
 def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
                      g_out, ba, bb, d, prune, interpret):
+    """(Nap, Nbp) Gram values, and two int32 counts summed over the pair
+    blocks: the tile sweeps run, and the live pairs over those sweeps."""
     Ti, Nap, _ = A.shape            # tile-stacked: (Ti, Nap, d*S)
     Nbp = B.shape[1]
     last = T_orig - 1
@@ -234,7 +254,11 @@ def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
             pl.BlockSpec((ba, 1), lambda i, j, g, m: (i, 0)),    # thresholds
             pl.BlockSpec((ba, bb), lambda i, j, g, m: (i, j)),   # alive0
         ],
-        out_specs=pl.BlockSpec((ba, bb), lambda i, j, g, m: (i, j)),
+        out_specs=[
+            pl.BlockSpec((ba, bb), lambda i, j, g, m: (i, j)),
+            # one (8, 128) counter tile per pair block, resident across g
+            pl.BlockSpec(_CNT_TILE, lambda i, j, g, m: (i, j)),
+        ],
         scratch_shapes=[
             pltpu.VMEM((Ti, ba * bb, S), jnp.float32),  # row_edge
             pltpu.VMEM((ba * bb, S), jnp.float32),    # col_edge
@@ -243,11 +267,18 @@ def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
             pltpu.VMEM((ba * bb, 1), jnp.float32),    # alive flags
         ],
     )
-    return pl.pallas_call(
+    ni, nj = grid[0], grid[1]
+    out, cnt = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Nap, Nbp), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((Nap, Nbp), jnp.float32),
+                   jax.ShapeDtypeStruct((ni * _CNT_TILE[0],
+                                         nj * _CNT_TILE[1]), jnp.float32)],
         interpret=interpret,
     )(meta, A, B, blocks, thr, alive0)
+    # per-block counts stay below 2**24, so the float32 tiles are exact
+    cnt = cnt.reshape(ni, _CNT_TILE[0], nj, _CNT_TILE[1])[:, 0, :, :2]
+    cnt = cnt.astype(jnp.int32)
+    return out, jnp.sum(cnt[..., 0]), jnp.sum(cnt[..., 1])
 
 
 def _pad_rows_cols(X: jnp.ndarray, n_to: int, t_to: int) -> jnp.ndarray:
@@ -278,10 +309,11 @@ def _pad_abandon_state(thresholds, alive0, Na, Nb, Nap, Nbp):
 
 
 def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
-                     T_orig: int | None = None, ba: int = 8, bb: int = 128,
+                     T_orig: int | None = None, ba: int = PAIR_BLOCK[0],
+                     bb: int = PAIR_BLOCK[1],
                      thresholds: jnp.ndarray | None = None,
                      alive0: jnp.ndarray | None = None,
-                     interpret: bool = False) -> jnp.ndarray:
+                     interpret: bool = False, return_counts: bool = False):
     """All-pairs SP-DTW Gram matrix via the fused block-sparse Pallas kernel.
 
     A: (Na, T) or (Na, T, d); B likewise. Returns (Na, Nb) SP-DTW values
@@ -293,6 +325,11 @@ def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
     pruned row boundaries + boundary-dead tile skips): entries whose true
     value exceeds the threshold may report +INF, entries at or below it
     are bit-identical to the exact sweep.
+
+    ``return_counts=True`` also returns the work done, as two int32
+    device scalars summed over the ba x bb pair blocks: the tile sweeps
+    the kernel ran, and the live pairs (alive, and with thresholds also
+    edge-live) over those sweeps — the pairs whose lanes did useful work.
     """
     from .backends import series_dim, to_tile_stack
     Na, T = A.shape[0], A.shape[1]
@@ -304,17 +341,19 @@ def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
     n_active = meta.shape[0]
     g_out = result_tile_step(meta, bsp.tile, T_orig)
     if g_out < 0:   # corner cell outside the support: no admissible path
-        return jnp.full((Na, Nb), INF, jnp.float32)
+        G = jnp.full((Na, Nb), INF, jnp.float32)
+        return (G, (jnp.int32(0), jnp.int32(0))) if return_counts else G
     Nap = ((Na + ba - 1) // ba) * ba
     Nbp = ((Nb + bb - 1) // bb) * bb
     thr, alive = _pad_abandon_state(thresholds, alive0, Na, Nb, Nap, Nbp)
-    out = _gram_spdtw_call(
+    out, sweeps, live = _gram_spdtw_call(
         jnp.asarray(meta), to_tile_stack(A, bsp.tile, bsp.T, n_to=Nap),
         to_tile_stack(B, bsp.tile, bsp.T, n_to=Nbp), jnp.asarray(bsp.blocks),
         thr, alive, S=bsp.tile, n_active=n_active, T_orig=T_orig,
         g_out=g_out, ba=ba, bb=bb, d=d, prune=thresholds is not None,
         interpret=interpret)
-    return out[:Na, :Nb]
+    return (out[:Na, :Nb], (sweeps, live)) if return_counts \
+        else out[:Na, :Nb]
 
 
 # ---------------------------------------------------------------------------
@@ -357,7 +396,9 @@ def _tile_scan(meta, blocks, get_xy, P, Tp, thr_p, alive_p, *, S, g_out, ri,
     off for soft callers). ``count=True`` additionally carries a (P, 1)
     int32 per-pair live-tile counter (tiles where the pair was alive
     with at least one live edge — the DP work actually attributable to
-    it) and returns it as a fourth element; incompatible with ``stash``.
+    it) and an int32 count of the tile sweeps run (with ``prune``, those
+    where some pair was live), and returns them as a fourth and fifth
+    element; incompatible with ``stash``.
     """
     assert not (stash and (prune or count)), \
         "prune/count are hard-sweep features; the stash path is soft-only"
@@ -367,7 +408,7 @@ def _tile_scan(meta, blocks, get_xy, P, Tp, thr_p, alive_p, *, S, g_out, ri,
 
     def step(carry, inp):
         if count:
-            row_edge, col_edge, corner, dri_out, alive, tiles = carry
+            row_edge, col_edge, corner, dri_out, alive, tiles, sweeps = carry
         else:
             row_edge, col_edge, corner, dri_out, alive = carry
         k, m = inp
@@ -421,8 +462,10 @@ def _tile_scan(meta, blocks, get_xy, P, Tp, thr_p, alive_p, *, S, g_out, ri,
         dri_out = jnp.where(k == g_out, dri, dri_out)
         if count:
             tiles = tiles + live.astype(jnp.int32)
+            ran = jnp.any(live) if prune else jnp.array(True)
+            sweeps = sweeps + ran.astype(jnp.int32)
             carry = (row_edge, rightcol, top_vec[:, S - 1:S], dri_out,
-                     alive, tiles)
+                     alive, tiles, sweeps)
         else:
             carry = (row_edge, rightcol, top_vec[:, S - 1:S], dri_out, alive)
         return carry, (rest[0] if stash else None)
@@ -430,10 +473,10 @@ def _tile_scan(meta, blocks, get_xy, P, Tp, thr_p, alive_p, *, S, g_out, ri,
     init = (jnp.full((P, Tp), neutral, dtype), inf_row,
             jnp.full((P, 1), neutral, dtype), inf_row, alive_p)
     if count:
-        init = init + (jnp.zeros((P, 1), jnp.int32),)
-        (row_edge, _, _, dri, alive, tiles), _ = jax.lax.scan(
+        init = init + (jnp.zeros((P, 1), jnp.int32), jnp.int32(0))
+        (row_edge, _, _, dri, alive, tiles, sweeps), _ = jax.lax.scan(
             step, init, (jnp.arange(n_active), meta))
-        return row_edge, dri, alive, tiles
+        return row_edge, dri, alive, tiles, sweeps
     (row_edge, _, _, dri, alive), Lstash = jax.lax.scan(
         step, init, (jnp.arange(n_active), meta))
     if stash:
@@ -462,7 +505,7 @@ def _gram_spdtw_scan_call(meta, A, B, blocks, thr, alive0, *, S, T_orig,
                      alive0.reshape(P, 1) > 0,
                      S=S, g_out=g_out, ri=ri, d=d, prune=prune, count=count)
     if count:
-        _, dri, alive, tiles = res
+        _, dri, alive, tiles, _ = res
     else:
         (_, dri, alive), tiles = res, None
     val = jax.lax.dynamic_slice_in_dim(dri, rj, 1, axis=1)
@@ -528,6 +571,7 @@ def gram_spdtw_scan(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
                                              "prune"))
 def _spdtw_paired_scan_call(meta, X, Y, blocks, thr, *, S, T_orig, g_out, d,
                             prune=False):
+    """(P,) values, the tile sweeps run and the live pairs over them."""
     P = X.shape[0]
     Tp = X.shape[1] // d
     last = T_orig - 1
@@ -537,17 +581,18 @@ def _spdtw_paired_scan_call(meta, X, Y, blocks, thr, *, S, T_orig, g_out, d,
         return (jax.lax.dynamic_slice(X, (0, ti * d * S), (P, d * S)),
                 jax.lax.dynamic_slice(Y, (0, tj * d * S), (P, d * S)))
 
-    _, dri, alive = _tile_scan(meta, blocks, get_xy, P, Tp,
-                               thr.reshape(P, 1), jnp.ones((P, 1), bool),
-                               S=S, g_out=g_out, ri=ri, d=d, prune=prune)
+    _, dri, alive, tiles, sweeps = _tile_scan(
+        meta, blocks, get_xy, P, Tp, thr.reshape(P, 1),
+        jnp.ones((P, 1), bool), S=S, g_out=g_out, ri=ri, d=d, prune=prune,
+        count=True)
     val = jax.lax.dynamic_slice_in_dim(dri, rj, 1, axis=1)
-    return jnp.where(alive, val, INF).reshape(P)
+    return jnp.where(alive, val, INF).reshape(P), sweeps, jnp.sum(tiles)
 
 
 def spdtw_paired_scan(x: jnp.ndarray, y: jnp.ndarray, bsp: BlockSparsePaths,
                       T_orig: int | None = None,
                       thresholds: jnp.ndarray | None = None,
-                      block_p: int = 4096) -> jnp.ndarray:
+                      block_p: int = 4096, return_counts: bool = False):
     """Batched *aligned-pair* SP-DTW over the active-tile schedule.
 
     x, y: (B, T) or (B, T, d) — pair p is (x[p], y[p]), no cross product.
@@ -557,7 +602,10 @@ def spdtw_paired_scan(x: jnp.ndarray, y: jnp.ndarray, bsp: BlockSparsePaths,
     gathering the pairs that outlived the bounds. Optional per-pair
     ``thresholds`` engage the early-abandon + in-DP PrunedDTW sweep
     (values <= threshold exact, above it possibly +INF, boundary-dead
-    tiles skipped outright).
+    tiles skipped outright). ``return_counts=True`` also returns the
+    counts of ``gram_spdtw_block``'s, summed over the ``block_p`` chunks:
+    the tile sweeps run (a chunk is one sweep unit) and the live pairs
+    over them.
     """
     from .backends import series_dim, to_tile_major
     B, T = x.shape[0], x.shape[1]
@@ -566,20 +614,27 @@ def spdtw_paired_scan(x: jnp.ndarray, y: jnp.ndarray, bsp: BlockSparsePaths,
     assert T_orig <= bsp.T
     g_out = result_tile_step(bsp.plan(), bsp.tile, T_orig)
     if g_out < 0:   # corner cell outside the support: no admissible path
-        return jnp.full((B,), INF, jnp.float32)
+        out = jnp.full((B,), INF, jnp.float32)
+        return (out, (jnp.int32(0), jnp.int32(0))) if return_counts else out
     meta = jnp.asarray(bsp.plan())
     blocks = jnp.asarray(bsp.blocks)
     xp = to_tile_major(x, bsp.tile, bsp.T)
     yp = to_tile_major(y, bsp.tile, bsp.T)
     thr = jnp.full((B,), INF, jnp.float32) if thresholds is None \
         else jnp.asarray(thresholds, jnp.float32)
-    outs = []
+    outs, sweeps, live = [], [], []
     for s in range(0, B, block_p):
-        outs.append(_spdtw_paired_scan_call(
+        o, sw, lv = _spdtw_paired_scan_call(
             meta, xp[s:s + block_p], yp[s:s + block_p], blocks,
             thr[s:s + block_p], S=bsp.tile, T_orig=T_orig, g_out=g_out,
-            d=d, prune=thresholds is not None))
-    return jnp.concatenate(outs, axis=0)
+            d=d, prune=thresholds is not None)
+        outs.append(o)
+        sweeps.append(sw)
+        live.append(lv)
+    out = jnp.concatenate(outs, axis=0)
+    if not return_counts:
+        return out
+    return out, (sum(sweeps[1:], sweeps[0]), sum(live[1:], live[0]))
 
 
 # ---------------------------------------------------------------------------

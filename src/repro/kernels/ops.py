@@ -18,6 +18,7 @@ call — bit-identical by construction, with a one-shot
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import warnings
 from typing import Optional
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import bounds as _bounds
 from repro.core.dtw import INF
 from repro.core.dtw import (band_mask as _band_mask, dtw as _dtw_pair,
@@ -41,8 +43,8 @@ from .dtw_wavefront import wavefront_dtw
 from .dtw_banded import banded_dtw
 from .spdtw_block import spdtw_block
 from .krdtw_wavefront import mask_to_diagonal_major, wavefront_log_krdtw
-from .gram_block import (gram_log_krdtw_block, gram_prefix_bound,
-                         gram_spdtw_block, gram_spdtw_scan,
+from .gram_block import (PAIR_BLOCK, gram_log_krdtw_block,
+                         gram_prefix_bound, gram_spdtw_block, gram_spdtw_scan,
                          prefix_tile_count, spdtw_paired_scan)
 from .soft_block import (gram_soft_spdtw_block, gram_soft_spdtw_scan,
                          soft_spdtw_batch, soft_spdtw_gram_batch,
@@ -178,7 +180,11 @@ def _spdtw_gram(A: jnp.ndarray, B: jnp.ndarray, *,
                 impl: str = "auto", tile: Optional[int] = None,
                 block_a: int = 64,
                 thresholds: Optional[jnp.ndarray] = None,
-                alive0: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                alive0: Optional[jnp.ndarray] = None,
+                return_counts: bool = False):
+    """``return_counts=True`` returns (G, counts): the Pallas kernel's
+    (tile sweeps, live pair sweeps) device scalars, None on the other
+    backends."""
     require = []
     if bsp is None and sp is None and bk.is_traced(weights):
         require.append(bk.TRACED_WEIGHTS)
@@ -188,15 +194,17 @@ def _spdtw_gram(A: jnp.ndarray, B: jnp.ndarray, *,
         out = _nested_cross(lambda a, b: _wdtw_pair(a, b, w), A, B, block_a)
         if alive0 is not None:
             out = jnp.where(jnp.asarray(alive0), out, INF)
-        return out
+        return (out, None) if return_counts else out
     bspr = bk.resolve_plan(sp, bsp, weights, tile=tile)
     if backend == "scan":
-        return gram_spdtw_scan(A, B, bspr, T_orig=A.shape[1],
-                               block_a=block_a, thresholds=thresholds,
-                               alive0=alive0)
+        out = gram_spdtw_scan(A, B, bspr, T_orig=A.shape[1],
+                              block_a=block_a, thresholds=thresholds,
+                              alive0=alive0)
+        return (out, None) if return_counts else out
     return gram_spdtw_block(A, B, bspr, T_orig=A.shape[1],
                             thresholds=thresholds, alive0=alive0,
-                            interpret=not bk.on_tpu())
+                            interpret=not bk.on_tpu(),
+                            return_counts=return_counts)
 
 
 def spdtw_gram(A: jnp.ndarray, B: jnp.ndarray, *,
@@ -436,98 +444,127 @@ def _knn_cascade(Q: jnp.ndarray, index: CorpusIndex, *, impl: str = "auto",
     Nc = C.shape[0]
     seed_k = min(seed_k, Nc)
     impl_r = bk.resolve(impl).name
+    # host spans time the eager cascade only: under jit or shard_map they
+    # would time the tracing
+    eager = not (bk.is_traced(Q) or bk.is_traced(C))
+
+    def stage(name, **attrs):
+        return tracing.span(name, **attrs) if eager \
+            else contextlib.nullcontext()
 
     # --- stage 0: centroid-seeded threshold (k + 1 DPs per query) ---
     cand = d_cand = None
     n_centroids = 0
     if centroid_model is not None and \
             getattr(centroid_model, "medoids", None) is not None:
-        Z = jnp.asarray(centroid_model.centroids, jnp.float32)
-        n_centroids = Z.shape[0]
-        Dc = _spdtw_gram(Q, Z, bsp=index.bsp, weights=index.weights,
-                         impl=impl, block_a=block_a)
-        best_c = jnp.argmin(Dc, axis=1)
-        cand = jnp.take(jnp.asarray(centroid_model.medoids, jnp.int32),
-                        best_c)                                # (Nq,)
-        d_cand = _pair_dp(Q, jnp.take(C, cand, axis=0), index, impl_r)
+        with stage("cascade.seed_dp"):
+            Z = jnp.asarray(centroid_model.centroids, jnp.float32)
+            n_centroids = Z.shape[0]
+            Dc = _spdtw_gram(Q, Z, bsp=index.bsp, weights=index.weights,
+                             impl=impl, block_a=block_a)
+            best_c = jnp.argmin(Dc, axis=1)
+            cand = jnp.take(jnp.asarray(centroid_model.medoids, jnp.int32),
+                            best_c)                            # (Nq,)
+            d_cand = _pair_dp(Q, jnp.take(C, cand, axis=0), index, impl_r)
 
-    # --- stage 1: banded endpoint bound (exact corners + the pinned
-    # first/last rows under per-row weight floors; DESIGN.md §14) ---
-    lb1 = _bounds.lb_kim_band_cross(Q, C, index.lo, index.hi,
-                                    index.wmin_rows, index.w00, index.wTT)
-    # --- stage 2: support-windowed envelopes, both orientations ---
-    lb2 = jnp.maximum(lb1, _bounds.lb_keogh_cross(
-        Q, index.env_lo, index.env_hi, index.wmin_rows))
-    q_lo, q_hi = _bounds.envelopes(Q, index.lo_t, index.hi_t)
-    lb2 = jnp.maximum(lb2, _bounds.lb_keogh_cross(
-        C, q_lo, q_hi, index.wmin_cols).T)
+    with stage("cascade.bounds"):
+        # --- stage 1: banded endpoint bound (exact corners + the pinned
+        # first/last rows under per-row weight floors; DESIGN.md §14) ---
+        lb1 = _bounds.lb_kim_band_cross(Q, C, index.lo, index.hi,
+                                        index.wmin_rows, index.w00,
+                                        index.wTT)
+        # --- stage 2: support-windowed envelopes, both orientations ---
+        lb2 = jnp.maximum(lb1, _bounds.lb_keogh_cross(
+            Q, index.env_lo, index.env_hi, index.wmin_rows))
+        q_lo, q_hi = _bounds.envelopes(Q, index.lo_t, index.hi_t)
+        lb2 = jnp.maximum(lb2, _bounds.lb_keogh_cross(
+            C, q_lo, q_hi, index.wmin_cols).T)
 
-    # --- seed thresholds: exact DP on the seed_k best-bounded candidates ---
-    _, seed_idx = jax.lax.top_k(-lb2, seed_k)                  # (Nq, k)
-    xq = jnp.repeat(Q, seed_k, axis=0)
-    yc = jnp.take(C, seed_idx.reshape(-1), axis=0)
-    seed_d = _pair_dp(xq, yc, index, impl_r).reshape(Nq, seed_k)
-    thr = jnp.min(seed_d, axis=1)                              # (Nq,)
-    if d_cand is not None:
-        thr = jnp.minimum(thr, d_cand)
+    with stage("cascade.seed_dp"):
+        # --- seed thresholds: exact DP on the seed_k best-bounded
+        # candidates ---
+        _, seed_idx = jax.lax.top_k(-lb2, seed_k)              # (Nq, k)
+        xq = jnp.repeat(Q, seed_k, axis=0)
+        yc = jnp.take(C, seed_idx.reshape(-1), axis=0)
+        seed_d = _pair_dp(xq, yc, index, impl_r).reshape(Nq, seed_k)
+        thr = jnp.min(seed_d, axis=1)                          # (Nq,)
+        if d_cand is not None:
+            thr = jnp.minimum(thr, d_cand)
 
-    # --- survivors so far: bound <= threshold (non-strict keeps ties) ---
-    rows = jnp.arange(Nq)[:, None]
-    alive2 = lb2 <= thr[:, None]
-    alive2 = alive2.at[rows, seed_idx].set(False)              # already known
-    if cand is not None:
-        alive2 = alive2.at[rows[:, 0], cand].set(False)
+        # --- survivors so far: bound <= threshold (non-strict keeps
+        # ties) ---
+        rows = jnp.arange(Nq)[:, None]
+        alive2 = lb2 <= thr[:, None]
+        alive2 = alive2.at[rows, seed_idx].set(False)          # known
+        if cand is not None:
+            alive2 = alive2.at[rows[:, 0], cand].set(False)
 
     # --- stage 3: truncated prefix-DP bound on the block plan ---
     n_prefix = prefix_tile_count(index.bsp, prefix_frac, T)
     if n_prefix > 0 and impl_r != "dense":
-        lb3 = gram_prefix_bound(Q, C, index.bsp, n_prefix, T_orig=T,
-                                block_a=block_a)
-        alive = alive2 & (lb3 <= thr[:, None])
+        with stage("cascade.prefix_bound"):
+            lb3 = gram_prefix_bound(Q, C, index.bsp, n_prefix, T_orig=T,
+                                    block_a=block_a)
+            alive = alive2 & (lb3 <= thr[:, None])
     else:
         lb3 = lb2
         alive = alive2
 
     # --- stage 4: exact DP on the survivors, early abandoning ---
-    eager = not (bk.is_traced(Q) or bk.is_traced(C) or bk.is_traced(thr))
-    D = jnp.full((Nq, Nc), INF, jnp.float32).at[rows, seed_idx].set(seed_d)
-    if cand is not None:
-        D = D.at[rows[:, 0], cand].set(d_cand)
-    if eager and impl_r == "scan":
-        # gather the survivors: the DP only ever touches those pairs
-        qi, ci = np.nonzero(np.asarray(alive))
-        if len(qi):
-            d_surv = _pair_dp(jnp.take(Q, qi, axis=0),
-                              jnp.take(C, ci, axis=0), index, impl_r,
-                              thresholds=jnp.take(thr, qi))
-            D = D.at[qi, ci].set(d_surv)
-        G_ab = None
-    else:
-        G = _spdtw_gram(Q, C, bsp=index.bsp, weights=index.weights,
-                        impl=impl, block_a=block_a, thresholds=thr,
-                        alive0=alive)
-        D = jnp.where(alive, G, D)
-        G_ab = G
-    nn = jnp.argmin(D, axis=1).astype(jnp.int32)
-    nnd = jnp.take_along_axis(D, nn[:, None], axis=1)[:, 0]
-    if not return_stats:
-        return nn, nnd
-    total = Nq * Nc
-    dp_pairs = _stat_int(alive.sum()) + Nq * (
-        seed_k + (n_centroids + 1 if cand is not None else 0))
-    abandoned = (alive & (D >= 1e29)) if G_ab is None else \
-        (alive & (G_ab >= 1e29))
-    stats = {
-        "n_queries": Nq, "n_candidates": Nc, "seed_k": seed_k,
-        "n_centroids": n_centroids,
-        "prefix_tiles": n_prefix, "plan_tiles": index.bsp.n_active,
-        "stage1_prune": jnp.mean((lb1 > thr[:, None]).astype(jnp.float32)),
-        "stage2_prune": jnp.mean((lb2 > thr[:, None]).astype(jnp.float32)),
-        "stage3_prune": jnp.mean((lb3 > thr[:, None]).astype(jnp.float32)),
-        "pre_dp_prune": 1.0 - dp_pairs / total,
-        "dp_pairs": dp_pairs,
-        "dp_abandoned": jnp.mean(abandoned.astype(jnp.float32)),
-    }
+    eager = eager and not bk.is_traced(thr)
+    counts = None
+    attrs = {"block_pairs": PAIR_BLOCK[0] * PAIR_BLOCK[1]} \
+        if impl_r == "pallas" else {}
+    with stage("cascade.survivor_dp", **attrs):
+        D = jnp.full((Nq, Nc), INF, jnp.float32).at[rows, seed_idx].set(
+            seed_d)
+        if cand is not None:
+            D = D.at[rows[:, 0], cand].set(d_cand)
+        if eager and impl_r == "scan":
+            # gather the survivors: the DP only ever touches those pairs
+            qi, ci = np.nonzero(np.asarray(alive))
+            counts = (0, 0)
+            if len(qi):
+                d_surv, counts = spdtw_paired_scan(
+                    jnp.take(Q, qi, axis=0), jnp.take(C, ci, axis=0),
+                    index.bsp, T_orig=T, thresholds=jnp.take(thr, qi),
+                    return_counts=True)
+                D = D.at[qi, ci].set(d_surv)
+            G_ab = None
+        else:
+            G, counts = _spdtw_gram(Q, C, bsp=index.bsp,
+                                    weights=index.weights, impl=impl,
+                                    block_a=block_a, thresholds=thr,
+                                    alive0=alive, return_counts=True)
+            D = jnp.where(alive, G, D)
+            G_ab = G
+
+    with stage("cascade.select"):
+        nn = jnp.argmin(D, axis=1).astype(jnp.int32)
+        nnd = jnp.take_along_axis(D, nn[:, None], axis=1)[:, 0]
+        if not return_stats:
+            return nn, nnd
+        total = Nq * Nc
+        dp_pairs = _stat_int(alive.sum()) + Nq * (
+            seed_k + (n_centroids + 1 if cand is not None else 0))
+        abandoned = (alive & (D >= 1e29)) if G_ab is None else \
+            (alive & (G_ab >= 1e29))
+        stats = {
+            "n_queries": Nq, "n_candidates": Nc, "seed_k": seed_k,
+            "n_centroids": n_centroids,
+            "prefix_tiles": n_prefix, "plan_tiles": index.bsp.n_active,
+            "stage1_prune": jnp.mean((lb1 > thr[:, None]).astype(
+                jnp.float32)),
+            "stage2_prune": jnp.mean((lb2 > thr[:, None]).astype(
+                jnp.float32)),
+            "stage3_prune": jnp.mean((lb3 > thr[:, None]).astype(
+                jnp.float32)),
+            "pre_dp_prune": 1.0 - dp_pairs / total,
+            "dp_pairs": dp_pairs,
+            "dp_abandoned": jnp.mean(abandoned.astype(jnp.float32)),
+        }
+        if counts is not None:
+            stats["dp_tile_sweeps"], stats["dp_alive_pair_sweeps"] = counts
     return nn, nnd, stats
 
 

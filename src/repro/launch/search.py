@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import SparsePaths, learn_sparse_paths
 from repro.core.engine import MeasureSpec, fit
 from repro.launch.stats import percentiles
@@ -42,10 +43,12 @@ from repro.launch.stats import percentiles
 _STAT_KEYS = ("stage1_prune", "stage2_prune", "stage3_prune",
               "pre_dp_prune", "dp_abandoned")
 _SKETCH_STAT_KEYS = ("shortlist_prune", "bound_prune", "pre_dp_prune")
-
-# legacy alias — the percentile helper moved to ``launch/stats.py`` so
-# search, the scenario harness and the monitor counters share one clamp
-_percentiles = percentiles
+_SKETCH_STAGES = ("embed", "shortlist", "rerank")
+# latency_ms stage <- the span whose durations it reports
+_LATENCY_SPANS = (("monitor", "search.monitor"), ("total", "search"))
+# the cascade's device-scalar work counts -> counter names
+_DP_COUNTERS = (("dp_tile_sweeps", "survivor_dp.tile_sweeps"),
+                ("dp_alive_pair_sweeps", "survivor_dp.alive_pair_sweeps"))
 
 
 @dataclasses.dataclass
@@ -79,8 +82,12 @@ class SearchEngine:
     (DESIGN.md §13): matmul shortlist of ``top_c`` candidates, exact
     cascade re-rank (skipped entirely with ``approx=True``) — sub-linear
     DP cost, exact whenever the shortlist covers the true neighbour.
-    Every mode records per-batch, per-stage wall-clock; ``stats()``
-    reports p50/p95/p99.
+    Every mode records each batch as spans (``repro.tracing``, on the
+    profiler's clock): ``search``, ``search.monitor``, the cascade's
+    ``cascade.*`` stages and ``search.readback``, with the programs
+    compiled meanwhile as ``compile``. ``stats()`` reports the batch and
+    monitor span durations as p50/p95/p99 and the spans and counters
+    themselves under ``trace``.
 
     ``refresh`` accepts a ``core.snapshot.SnapshotStore`` (DESIGN.md
     §16): before each batch the engine adopts the store's current
@@ -186,14 +193,15 @@ class SearchEngine:
             self._n_refreshes += 1
 
     def reset_stats(self) -> None:
-        """Zero every serving accumulator: prune counters, latency
-        samples, pair/query totals, refresh-lag bookkeeping. Call
+        """Zero every serving accumulator: prune counters, the span
+        recorder, pair/query totals, refresh-lag bookkeeping. Call
         between streams so each reports independent stats — without
         this, a second ``stream_search`` pass folds the first pass's
         counters into its rates and percentiles."""
         keys = _SKETCH_STAT_KEYS if self.mode == "sketch" else _STAT_KEYS
         self._stats_acc: Dict[str, float] = {k: 0.0 for k in keys}
-        self._lat: Dict[str, List[float]] = {}
+        self._trace = tracing.Recorder()
+        self._sketch_lat: Dict[str, List[float]] = {}
         self._pairs_total = 0
         self._pairs_dp = 0
         self._queries = 0
@@ -201,9 +209,6 @@ class SearchEngine:
         self._lag_sum = 0
         self._lag_max = 0
         self._lag_n = 0
-
-    def _record_lat(self, stage: str, seconds: float) -> None:
-        self._lat.setdefault(stage, []).append(seconds)
 
     @property
     def measure(self):
@@ -216,37 +221,36 @@ class SearchEngine:
 
         In centroid mode ``nn_idx`` indexes the centroid set (k DPs per
         query, counted as such in the pair stats)."""
-        self._maybe_refresh()
-        Q = jnp.asarray(queries, jnp.float32)
-        n = Q.shape[0]
-        if self.monitor is not None:
-            # corpus analytics tier (DESIGN.md §17): anomaly decisions +
-            # drift window on this batch, timed as its own serving stage
-            t_m = time.time()
-            self.monitor.observe(Q, impl=self.impl)
-            self._record_lat("monitor", time.time() - t_m)
-        t0 = time.time()
+        n = len(queries)
+        with tracing.recording(self._trace), \
+                tracing.span("search", n=n, mode=self.mode):
+            Q = jnp.asarray(queries, jnp.float32)
+            self._maybe_refresh()
+            if self.monitor is not None:
+                # corpus analytics tier (DESIGN.md §17): anomaly
+                # decisions + drift window on this batch
+                with tracing.span("search.monitor"):
+                    self.monitor.observe(Q, impl=self.impl)
+            return self._serve(Q, n)
+
+    def _serve(self, Q, n: int) -> Tuple[np.ndarray, np.ndarray]:
+        self._queries += n
+        self._pairs_total += n * self.index.size
         if self.mode == "centroid":
             from repro.cluster import nearest_centroid
             idx, dist = nearest_centroid(Q, self.centroid_model,
                                          impl=self.impl)
-            idx, dist = np.asarray(idx), np.asarray(dist)
-            self._record_lat("total", time.time() - t0)
-            self._queries += n
-            self._pairs_total += n * self.index.size
+            with tracing.span("search.readback"):
+                idx, dist = np.asarray(idx), np.asarray(dist)
             self._pairs_dp += n * self.centroid_model.k
             return idx, dist
         if self.sharded is not None:
             # sharded tier: per-shard cascade + global top-k merge
-            # (DESIGN.md §15) — per-stage prune counters live inside the
-            # shard_map trace, so only wall-clock is recorded here
+            # (DESIGN.md §15) — per-stage prune counters and stage spans
+            # live inside the shard_map trace, so neither is recorded
             nn, dist = self.sharded.knn(Q)
-            nn = np.asarray(jax.block_until_ready(nn))
-            dist = np.asarray(dist)
-            self._record_lat("total", time.time() - t0)
-            self._queries += n
-            self._pairs_total += n * self.index.size
-            return nn, dist
+            with tracing.span("search.readback"):
+                return np.asarray(nn), np.asarray(dist)
         if self.mode == "sketch":
             nn, dist, st = self.engine.knn(
                 Q, impl=self.impl, mode="sketch", top_c=self.top_c,
@@ -255,16 +259,18 @@ class SearchEngine:
             nn, dist, st = self.engine.knn(
                 Q, impl=self.impl, seed_k=self.seed_k,
                 prefix_frac=self.prefix_frac, return_stats=True)
-        nn, dist = np.asarray(nn), np.asarray(dist)
-        self._record_lat("total", time.time() - t0)
-        for stage in ("embed", "shortlist", "rerank"):
+        for key, name in _DP_COUNTERS:
+            if key in st:
+                tracing.count(name, st[key])
+        with tracing.span("search.readback"):
+            nn, dist = np.asarray(nn), np.asarray(dist)
+            for k in self._stats_acc:
+                self._stats_acc[k] += float(st.get(k, 0.0)) * n
+            self._pairs_dp += int(st["dp_pairs"])
+        for stage in _SKETCH_STAGES:
             if f"t_{stage}_s" in st:
-                self._record_lat(stage, float(st[f"t_{stage}_s"]))
-        for k in self._stats_acc:
-            self._stats_acc[k] += float(st.get(k, 0.0)) * n
-        self._queries += n
-        self._pairs_total += n * self.index.size
-        self._pairs_dp += int(st["dp_pairs"])
+                self._sketch_lat.setdefault(stage, []).append(
+                    float(st[f"t_{stage}_s"]))
         return nn, dist
 
     def stats(self) -> Dict[str, float]:
@@ -272,8 +278,10 @@ class SearchEngine:
         stage keys only exist in cascade / sketch mode — centroid serving
         runs no bounds, and all-zero prune rates would read as a broken
         cascade), plus per-stage p50/p95/p99 batch latency under
-        ``latency_ms`` (sketch mode breaks out embed / shortlist /
-        re-rank; every mode records the total)."""
+        ``latency_ms``: ``total`` and ``monitor`` from the ``search`` and
+        ``search.monitor`` spans still in the recorder's ring, and in
+        sketch mode embed / shortlist / re-rank as the tier times them.
+        ``trace`` holds the recorder's spans and counter totals."""
         if self._queries == 0:
             return {}
         if self.sharded is not None:
@@ -300,8 +308,17 @@ class SearchEngine:
                 "max_lag": int(self._lag_max)}
         if self.monitor is not None:
             out["monitor"] = self.monitor.counters()
+        trace = self._trace.snapshot()
+        lat: Dict[str, List[float]] = {}
+        for stage, name in _LATENCY_SPANS:
+            d = [(sp["end_ns"] - sp["start_ns"]) * 1e-9
+                 for sp in trace["spans"] if sp["name"] == name]
+            if d:
+                lat[stage] = d
+        lat.update(self._sketch_lat)
         out["latency_ms"] = {stage: percentiles(v)
-                             for stage, v in self._lat.items()}
+                             for stage, v in lat.items()}
+        out["trace"] = trace
         return out
 
 
@@ -507,6 +524,8 @@ def main():
               centroids=args.centroids, gamma=args.gamma,
               sketch_r=args.sketch_r, top_c=args.top_c, approx=args.approx,
               shards=args.shards)
+    # the spans are for tools reading stats(), not for the console
+    out["stats"].pop("trace", None)
     print(json.dumps(out, indent=1, default=float))
     lat = out["stats"].get("latency_ms", {})
     for stage in ("embed", "shortlist", "rerank", "total"):

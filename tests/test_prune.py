@@ -210,6 +210,62 @@ def test_indp_live_tiles_monotone(seed):
     assert prev.mean() < bsp.n_active       # the tightest sweep skipped work
 
 
+# ------------------------------------------- survivor DP work counters
+def _counted(A, B, bsp, T, thr, alive0=None, ba=4, bb=8):
+    """(tile sweeps, live pair sweeps) of the Pallas Gram kernel."""
+    _, (sw, lv) = gram_spdtw_block(
+        A, B, bsp, T_orig=T, ba=ba, bb=bb, interpret=True, thresholds=thr,
+        alive0=None if alive0 is None else jnp.asarray(alive0),
+        return_counts=True)
+    return int(sw), int(lv)
+
+
+@pytest.mark.parametrize("case", ["all_alive", "none_alive", "one_per_block"])
+def test_gram_kernel_counts_sweeps(case):
+    """+INF thresholds: every alive pair is live in every tile, so a block
+    with any alive pair sweeps all n_active tiles; a block with none
+    sweeps nothing."""
+    T = 24
+    bsp = block_sparsify(_learned_sp(T), tile=8)
+    A, B = _series(8, T, seed=11), _series(16, T, seed=12)
+    thr = jnp.full((8,), jnp.float32(1e30))
+    n_blocks = (8 // 4) * (16 // 8)
+    if case == "all_alive":
+        assert _counted(A, B, bsp, T, thr) == (
+            n_blocks * bsp.n_active, 8 * 16 * bsp.n_active)
+    elif case == "none_alive":
+        assert _counted(A, B, bsp, T, thr, np.zeros((8, 16), bool)) == (0, 0)
+    else:
+        alive0 = np.zeros((8, 16), bool)
+        alive0[::4, ::8] = True                  # one pair in each block
+        sw, lv = _counted(A, B, bsp, T, thr, alive0)
+        assert sw == lv == n_blocks * bsp.n_active
+
+
+@pytest.mark.parametrize("shape", [(4, 8), (8, 24)], ids=["one_block",
+                                                          "six_blocks"])
+def test_gram_kernel_counts_match_the_scan_path(shape):
+    """The cascade's two survivor engines count the same work: the Pallas
+    Gram kernel over (thresholds, alive0), and the scan path's paired
+    sweep over the alive pairs. Live pair sweeps agree always; tile
+    sweeps where the pairs form one block in both."""
+    T = 24
+    na, nb = shape
+    bsp = block_sparsify(_learned_sp(T), tile=8)
+    A, B = _series(na, T, seed=13), _series(nb, T, seed=14)
+    base = np.asarray(gram_spdtw_scan(A, B, bsp, T_orig=T))
+    thr = jnp.asarray(np.partition(base, 2, axis=1)[:, 2] * 1.5)
+    alive0 = np.random.default_rng(15).random((na, nb)) < 0.6
+    sw, lv = _counted(A, B, bsp, T, thr, alive0)
+    qi, ci = np.nonzero(alive0)
+    _, (sw2, lv2) = spdtw_paired_scan(A[qi], B[ci], bsp, T_orig=T,
+                                      thresholds=thr[qi], return_counts=True)
+    assert 0 < lv == int(lv2)
+    if shape == (4, 8):
+        assert sw == int(sw2)
+    assert lv < len(qi) * bsp.n_active         # the thresholds pruned work
+
+
 def test_paired_scan_prune_exact_below_threshold():
     T = 24
     bsp = block_sparsify(_learned_sp(T, gamma=0.5), tile=8)

@@ -17,8 +17,9 @@ import numpy as np
 import pytest
 
 from benchmarks.check_artifacts import check_file
-from repro.launch.search import SearchEngine, _percentiles
+from repro.launch.search import SearchEngine
 from repro.launch import scenarios
+from repro.launch.stats import percentiles
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
@@ -26,14 +27,14 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 # ------------------------------------------------- percentile clamp fix
 def test_percentiles_empty_stream_clamps_to_zero():
     """No samples must not poison the artifact with NaN."""
-    p = _percentiles([])
+    p = percentiles([])
     assert set(p) == {"p50", "p95", "p99"}
     assert all(v == 0.0 for v in p.values())
 
 
 def test_percentiles_single_element_stream():
     """One sample reports that sample at every percentile (no NaN)."""
-    p = _percentiles([0.25])
+    p = percentiles([0.25])
     assert all(np.isfinite(v) and v == pytest.approx(250.0)
                for v in p.values())
 
