@@ -36,8 +36,10 @@ window/weight vectors are derived host-side once per learned support.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -200,6 +202,42 @@ def lb_keogh_cross(Q: jnp.ndarray, env_lo: jnp.ndarray, env_hi: jnp.ndarray,
     rows = [_keogh_penalty(Q[s:s + block_q], env_lo, env_hi, wmin)
             for s in range(0, Q.shape[0], block_q)]
     return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+
+
+def cascade_bounds(Q: jnp.ndarray, C: jnp.ndarray, env_lo: jnp.ndarray,
+                   env_hi: jnp.ndarray, *, lo, hi, wmin_rows, lo_t, hi_t,
+                   wmin_cols, w00: float, wTT: float
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The cascade's bound stage as one compiled program: (lb1, lb2).
+
+    lb1 is the banded LB_Kim; lb2 the max of lb1, LB_Keogh against the
+    candidate envelopes (env_lo, env_hi), and LB_Keogh in the candidate
+    orientation against the query envelopes. Q: (Nq, T[, d]); C and the
+    envelopes: (Nc, T[, d]). The host-side support vectors (lo .. wTT,
+    a ``CorpusIndex``'s static fields) drive Python control flow, so they
+    are static to the program: one program per support and batch shape.
+    The arrays stay arguments, so sliced or refreshed corpora reuse it;
+    under an outer jit or shard_map trace the program inlines.
+    """
+    statics = tuple(tuple(np.asarray(v).tolist()) for v in
+                    (lo, hi, wmin_rows, lo_t, hi_t, wmin_cols))
+    return _cascade_bounds(jnp.asarray(Q, jnp.float32),
+                           jnp.asarray(C, jnp.float32), env_lo, env_hi,
+                           statics + (float(w00), float(wTT)))
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _cascade_bounds(Q, C, env_lo, env_hi, statics):
+    lo, hi, wmin_rows, lo_t, hi_t, wmin_cols = (np.asarray(v)
+                                                for v in statics[:6])
+    w00, wTT = statics[6:]
+    lb1 = lb_kim_band_cross(Q, C, lo, hi, wmin_rows, w00, wTT)
+    lb2 = jnp.maximum(lb1, _keogh_penalty(Q, env_lo, env_hi, wmin_rows))
+    q_lo, q_hi = envelopes(Q, lo_t, hi_t)
+    # no chunking over the corpus: the compiler fuses the penalty into
+    # its reduction, so the (Nc, Nq, T) excess is never materialised
+    lb2 = jnp.maximum(lb2, _keogh_penalty(C, q_lo, q_hi, wmin_cols).T)
+    return lb1, lb2
 
 
 # ---------------------------------------------------------------------------

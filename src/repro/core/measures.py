@@ -23,7 +23,7 @@ nested vmap for the dense measures). Nothing on this path materializes the
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +148,15 @@ class CorpusIndex:
     def size(self) -> int:
         """Number of indexed corpus series."""
         return int(self.corpus.shape[0])
+
+    def cascade_bounds(self, Q) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """(lb1, lb2) of the cascade's bound stage for queries Q against
+        this index, one compiled program (``bounds.cascade_bounds``)."""
+        return bounds.cascade_bounds(
+            Q, self.corpus, self.env_lo, self.env_hi, lo=self.lo,
+            hi=self.hi, wmin_rows=self.wmin_rows, lo_t=self.lo_t,
+            hi_t=self.hi_t, wmin_cols=self.wmin_cols, w00=self.w00,
+            wTT=self.wTT)
 
     def take(self, sel) -> "CorpusIndex":
         """Candidate-sliced view of this index (the sharding primitive).

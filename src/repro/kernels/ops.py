@@ -469,16 +469,10 @@ def _knn_cascade(Q: jnp.ndarray, index: CorpusIndex, *, impl: str = "auto",
 
     with stage("cascade.bounds"):
         # --- stage 1: banded endpoint bound (exact corners + the pinned
-        # first/last rows under per-row weight floors; DESIGN.md §14) ---
-        lb1 = _bounds.lb_kim_band_cross(Q, C, index.lo, index.hi,
-                                        index.wmin_rows, index.w00,
-                                        index.wTT)
-        # --- stage 2: support-windowed envelopes, both orientations ---
-        lb2 = jnp.maximum(lb1, _bounds.lb_keogh_cross(
-            Q, index.env_lo, index.env_hi, index.wmin_rows))
-        q_lo, q_hi = _bounds.envelopes(Q, index.lo_t, index.hi_t)
-        lb2 = jnp.maximum(lb2, _bounds.lb_keogh_cross(
-            C, q_lo, q_hi, index.wmin_cols).T)
+        # first/last rows under per-row weight floors; DESIGN.md §14);
+        # stage 2: support-windowed envelopes, both orientations. One
+        # compiled program for both stages ---
+        lb1, lb2 = index.cascade_bounds(Q)
 
     with stage("cascade.seed_dp"):
         # --- seed thresholds: exact DP on the seed_k best-bounded
@@ -604,13 +598,7 @@ def _krdtw_knn_cascade(Q: jnp.ndarray, index: CorpusIndex, *,
     nu = index.nu
 
     # --- min-plus bound b1 on the unit-weight masked path cost ---
-    b1 = _bounds.lb_kim_band_cross(Q, C, index.lo, index.hi,
-                                   index.wmin_rows, index.w00, index.wTT)
-    b1 = jnp.maximum(b1, _bounds.lb_keogh_cross(
-        Q, index.env_lo, index.env_hi, index.wmin_rows))
-    q_lo, q_hi = _bounds.envelopes(Q, index.lo_t, index.hi_t)
-    b1 = jnp.maximum(b1, _bounds.lb_keogh_cross(
-        C, q_lo, q_hi, index.wmin_cols).T)
+    _, b1 = index.cascade_bounds(Q)
     # --- b2: every K2 path pays the aligned endpoint factors ---
     b2 = (Q[:, 0, None] - C[None, :, 0]) ** 2
     if T > 1:

@@ -142,7 +142,6 @@ class AnomalyScorer:
         the raw sketch k-NN statistic. Stats count the fast-path
         certificates and the escalations (the borderline band that paid
         a full cascade)."""
-        from repro.core import bounds as _bounds
         from repro.kernels import backends as bk
         from repro.kernels.ops import _pair_dp
         from repro.core.sketch import sketch_shortlist
@@ -175,14 +174,7 @@ class AnomalyScorer:
 
         # flag fast path: min over candidates of the admissible §4 lower
         # bounds above tau certifies every candidate farther than tau
-        lb = _bounds.lb_kim_band_cross(Q, index.corpus, index.lo, index.hi,
-                                       index.wmin_rows, index.w00,
-                                       index.wTT)
-        lb = jnp.maximum(lb, _bounds.lb_keogh_cross(
-            Q, index.env_lo, index.env_hi, index.wmin_rows))
-        q_lo, q_hi = _bounds.envelopes(Q, index.lo_t, index.hi_t)
-        lb = jnp.maximum(lb, _bounds.lb_keogh_cross(
-            index.corpus, q_lo, q_hi, index.wmin_cols).T)
+        _, lb = index.cascade_bounds(Q)
         certified = np.asarray(jnp.min(lb, axis=1) > tau)
 
         flags = certified.copy()

@@ -13,8 +13,8 @@ import jax.numpy as jnp
 
 from repro.core import (SparsePaths, block_sparsify, build_corpus_index,
                         envelopes, learn_sparse_paths, lb_keogh_cross,
-                        lb_kim_cross, make_measure, row_min_weights,
-                        support_extents)
+                        lb_kim_band_cross, lb_kim_cross, make_measure,
+                        row_min_weights, support_extents)
 from repro.kernels import (gram_prefix_bound, gram_spdtw_block,
                            gram_spdtw_scan, prefix_tile_count,
                            spdtw_paired_scan)
@@ -149,6 +149,82 @@ def test_prefix_bound_admissible_and_monotone():
         # deeper prefixes only tighten (row-min of later rows >= earlier)
         assert (lb >= prev - 1e-4).all()
         prev = lb
+
+
+# ------------------------------------------------- compiled bound stage
+def _stage_index(support, d, Nc, T=24, seed=4):
+    """A corpus index over a random or learned support, (Nc, T[, d])."""
+    sp = _random_sp(T, density=0.3, seed=seed) if support == "random" \
+        else _learned_sp(T, theta=1.0, gamma=0.5)
+    rng = np.random.default_rng(seed)
+    shape = (Nc, T) if d is None else (Nc, T, d)
+    C = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    return sp, build_corpus_index(C, sp.weights)
+
+
+def _stage_queries(n, T, d, seed=5):
+    shape = (n, T) if d is None else (n, T, d)
+    return jnp.asarray(np.random.default_rng(seed).normal(
+        size=shape).astype(np.float32))
+
+
+def _eager_stage(Q, idx):
+    """The bound stage as eager calls: banded Kim, then Keogh both ways
+    (the corpus orientation chunked over 256 series at a time)."""
+    C = idx.corpus
+    lb1 = np.asarray(lb_kim_band_cross(Q, C, idx.lo, idx.hi, idx.wmin_rows,
+                                       idx.w00, idx.wTT))
+    lb2 = np.maximum(lb1, np.asarray(lb_keogh_cross(
+        Q, idx.env_lo, idx.env_hi, idx.wmin_rows)))
+    qlo, qhi = envelopes(Q, idx.lo_t, idx.hi_t)
+    lb2 = np.maximum(lb2, np.asarray(lb_keogh_cross(
+        C, qlo, qhi, idx.wmin_cols)).T)
+    return lb1, lb2
+
+
+@pytest.mark.parametrize("Nc", [7, 300])
+@pytest.mark.parametrize("d", [None, 3])
+@pytest.mark.parametrize("support", ["random", "learned"])
+def test_cascade_bounds_equal_the_eager_stage(support, d, Nc):
+    """The one-program bound stage equals the eager composition; 300
+    corpus series are not a multiple of the eager chunk of 256."""
+    _, idx = _stage_index(support, d, Nc)
+    Q = _stage_queries(5, 24, d)
+    got = idx.cascade_bounds(Q)
+    for g, e in zip(got, _eager_stage(Q, idx)):
+        g = np.asarray(g)
+        assert g.shape == (5, Nc) and g.dtype == np.float32
+        np.testing.assert_allclose(g, e, rtol=1e-5, atol=0)
+
+
+@pytest.mark.parametrize("d", [None, 3])
+@pytest.mark.parametrize("support", ["random", "learned"])
+def test_cascade_bounds_admissible(support, d):
+    """lb1 <= lb2 <= exact SP-DTW on every feasible pair."""
+    sp, idx = _stage_index(support, d, 6)
+    Q = _stage_queries(4, 24, d)
+    lb1, lb2 = (np.asarray(b) for b in idx.cascade_bounds(Q))
+    full = _oracle(Q, idx.corpus, sp.weights)
+    feas = full < 1e29
+    assert feas.any()
+    assert (lb1 <= lb2).all()
+    assert (lb2[feas] <= full[feas] * (1 + 1e-5) + 1e-5).all()
+
+
+@pytest.mark.parametrize("row", [1, 12], ids=["band_row", "middle_row"])
+def test_cascade_bounds_empty_row_is_inf(row):
+    """A support with an empty row admits no path: lb2 is +INF
+    everywhere, and lb1 too where the row lies in Kim's band."""
+    T = 24
+    w = np.asarray(_random_sp(T, density=0.4, seed=6).weights).copy()
+    w[row] = 0.0
+    idx = build_corpus_index(_series(9, T), w)
+    Q = _series(3, T)
+    lb1, lb2 = (np.asarray(b) for b in idx.cascade_bounds(Q))
+    assert (lb2 >= 1e29).all()
+    assert (lb1 >= 1e29).all() == (row == 1)
+    for g, e in zip((lb1, lb2), _eager_stage(Q, idx)):
+        np.testing.assert_allclose(g, e, rtol=1e-5, atol=0)
 
 
 # --------------------------------------------------- early-abandon engines

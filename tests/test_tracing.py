@@ -142,6 +142,28 @@ def test_compile_spans_for_a_new_batch_shape():
     assert all(s["attrs"]["program"] for s in comp)
 
 
+def test_bound_stage_is_one_program_built_once():
+    """Two same-shape batches build the bound stage's program once; the
+    cascade traced under jit still returns the eager cascade's answers."""
+    from repro.core import bounds
+    C = _corpus(n=44, T=18, seed=4)
+    eng = SearchEngine(C, kind="spdtw", impl="scan")
+    bounds._cascade_bounds.clear_cache()
+    eng.search(C[:6])
+    eng.search(C[6:12])
+    assert bounds._cascade_bounds._cache_size() == 1
+    built = [s for s in eng.stats()["trace"]["spans"]
+             if s["name"] == "compile"
+             and "cascade_bounds" in s["attrs"]["program"]]
+    assert len(built) == 1
+    index = eng.index
+    Q = jnp.asarray(C[12:18] + 0.1)
+    nn, nnd = ops._knn_cascade(Q, index, impl="scan")
+    jnn, jnnd = jax.jit(lambda q: ops._knn_cascade(q, index, impl="scan"))(Q)
+    assert np.array_equal(np.asarray(jnn), np.asarray(nn))
+    np.testing.assert_allclose(np.asarray(jnnd), np.asarray(nnd), rtol=1e-6)
+
+
 def _profile_start_ns(profile) -> int:
     for plane in profile.planes:
         for key, value in plane.stats:
