@@ -507,8 +507,12 @@ def _knn_cascade(Q: jnp.ndarray, index: CorpusIndex, *, impl: str = "auto",
     # --- stage 4: exact DP on the survivors, early abandoning ---
     eager = eager and not bk.is_traced(thr)
     counts = None
-    attrs = {"block_pairs": PAIR_BLOCK[0] * PAIR_BLOCK[1]} \
-        if impl_r == "pallas" else {}
+    # the plan the kernel sweeps: each surviving pair runs over plan_tiles
+    # tiles of tile x tile cells
+    attrs = {} if impl_r == "dense" else {"tile": index.bsp.tile,
+                                          "plan_tiles": index.bsp.n_active}
+    if impl_r == "pallas":
+        attrs["block_pairs"] = PAIR_BLOCK[0] * PAIR_BLOCK[1]
     with stage("cascade.survivor_dp", **attrs):
         D = jnp.full((Nq, Nc), INF, jnp.float32).at[rows, seed_idx].set(
             seed_d)

@@ -27,12 +27,19 @@ FORDA = (3601, 1320, 500)
 ELECTRICDEVICES = (8926, 7711, 96)
 
 
-@pytest.mark.parametrize("split,seed", [
-    (FORDA, 1292441283), (FORDA, 2),
-    (ELECTRICDEVICES, 0), (ELECTRICDEVICES, 1),
-    (ELECTRICDEVICES, 1292441283)],
-    ids=["forda-1292441283", "forda-2", "electricdevices-0",
-         "electricdevices-1", "electricdevices-1292441283"])
+# seeds of the size the benchmark draws: each FordA seed costs some 8 s
+# on the CPU
+FORDA_SEEDS = (1292441283, 2, 3037000493, 2147483659, 3221225473,
+               4294967311, 987654321123, 2305843009)
+ELECTRICDEVICES_SEEDS = (0, 1, 1292441283)
+
+
+@pytest.mark.parametrize(
+    "split,seed",
+    [(FORDA, s) for s in FORDA_SEEDS]
+    + [(ELECTRICDEVICES, s) for s in ELECTRICDEVICES_SEEDS],
+    ids=[f"forda-{s}" for s in FORDA_SEEDS]
+    + [f"electricdevices-{s}" for s in ELECTRICDEVICES_SEEDS])
 def test_learned_support_equals_the_reference(split, seed):
     n_train, n_test, T = split
     ds = data.make_cbf(n_train, n_test, T, rng(seed, 0))

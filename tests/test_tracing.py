@@ -1,8 +1,9 @@
 """The span recorder (``repro.tracing``) and the serving spans built on it:
-nesting under each batch's ``search`` span, reset and ring bound, no spans
-under ``jit``, ``compile`` spans for programs built mid-stream, the
-``latency_ms`` block read from spans, and the clock: every recorded
-interval matches its ``TraceAnnotation`` event in a profiler trace."""
+nesting under each batch's ``search`` span, the plan on the survivor DP
+span, reset and ring bound, no spans under ``jit``, ``compile`` spans for
+programs built mid-stream, the ``latency_ms`` block read from spans, and
+the clock: every recorded interval matches its ``TraceAnnotation`` event
+in a profiler trace."""
 import glob
 
 import jax
@@ -79,6 +80,16 @@ def test_survivor_counters_reach_stats(served):
     assert c["survivor_dp.tile_sweeps"] > 0
     assert c["survivor_dp.alive_pair_sweeps"] >= \
         c["survivor_dp.tile_sweeps"]
+
+
+def test_survivor_dp_span_carries_the_plan(served):
+    eng, st = served
+    bsp = eng.index.bsp
+    spans = [s for s in st["trace"]["spans"]
+             if s["name"] == "cascade.survivor_dp"]
+    assert len(spans) == 3
+    for s in spans:
+        assert s["attrs"] == {"tile": bsp.tile, "plan_tiles": bsp.n_active}
 
 
 def test_reset_clears_and_the_ring_stays_bounded(served):
