@@ -10,35 +10,42 @@ SP-DTW Gram kernel (``gram_spdtw_block``)
   * grid = (A-tile, B-tile, active-path-tile); the innermost axis sweeps the
     row-major schedule of active S x S weight tiles (scalar-prefetched meta:
     ti, tj, slot, top/left/diag-active bits);
-  * each (Ti, ba, d*S) A-stripe / (Ti, bb, d*S) B-stripe (tile-stacked,
-    ``backends.to_tile_stack``; ba x bb = 8 x 128 keeps the per-pair
-    blocks lane-legal) is block-specced with an index
-    map constant in the inner axes, so Pallas's pipeline loads it into VMEM
-    **once** per (A-tile, B-tile) step and revisits it for the whole active
-    sweep — no HBM pair expansion ever exists;
-  * inside a grid step the ba x bb pair batch is formed in VMEM (sublane
-    repeat / concat) and pushed through the shared ``tile_sweep`` DP
-    (min-plus lane scan per row, identical math to ``spdtw_block``);
-  * DP state flows between active tiles through VMEM scratch sized for the
-    ba*bb pair batch: ``row_edge`` (bottom edges per tile column),
-    ``col_edge`` (right edge of the left tile), ``corner_next`` (top-left
-    corner), ``d_ri`` (result-row capture). All cross-tile reads are guarded
-    by the prefetched neighbour bits so skipped tiles contribute +INF, and
-    every value consumed in a (A-tile, B-tile) step was produced in the same
-    step's sweep — scratch never leaks between pair blocks;
+  * each (Ti, ba, d*S) A-stripe (queries on sublanes) / (Ti, d*S, bb)
+    B-stripe (corpus series on lanes; both tile-stacked from
+    ``backends.to_tile_stack``, ba x bb = 8 x 128 keeps the blocks
+    lane-legal) is block-specced with an index map constant in the inner
+    axes, so Pallas's pipeline loads it into VMEM **once** per (A-tile,
+    B-tile) step and revisits it for the whole active sweep — no HBM pair
+    expansion ever exists;
+  * the ba x bb pair block is one (ba, bb) value, pair (ia, ib) at
+    (sublane ia, lane ib): x enters as a lane broadcast, y as a sublane
+    broadcast, the weight as an SMEM scalar, and a tile row is S such
+    values, one per column, so the DP is elementwise over the block with
+    no lane scan. Its arithmetic is ``spdtw_block.tile_sweep``'s, step
+    for step, and the parity tests hold it bit-identical to the scan
+    engine below;
+  * DP state flows between active tiles through VMEM scratch, stacks of
+    (ba, bb) blocks on leading axes: ``row_edge`` (bottom edges per tile
+    column), ``col_edge`` (right edge of the left tile), ``corner_next``
+    (top-left corner), ``d_ri`` (result-cell capture). All cross-tile
+    reads are guarded by the prefetched neighbour bits so skipped tiles
+    contribute +INF, and every value consumed in a (A-tile, B-tile) step
+    was produced in the same step's sweep — scratch never leaks between
+    pair blocks;
   * work is Na*Nb*n_active*S^2 instead of Na*Nb*T^2: the paper's
     "complexity linear in surviving cells" claim, at tile granularity, on
     the workload that matters.
 
 SP-K_rdtw Gram kernel (``gram_log_krdtw_block``)
-  * grid = (A-tile, B-tile); the pair batch is formed in VMEM the same way
-    and swept with the shared anti-diagonal ``krdtw_sweep`` (log-rescaled
-    K1+K2 recursion) under the diagonal-major learned support mask.
+  * grid = (A-tile, B-tile); the (ba*bb) pair batch is formed in VMEM
+    (sublane repeat / concat, ``_pair_batch``) and swept with the shared
+    anti-diagonal ``krdtw_sweep`` (log-rescaled K1+K2 recursion) under
+    the diagonal-major learned support mask.
 
 ``gram_spdtw_scan`` is the same active-tile schedule as a jnp ``lax.scan``
-(reusing ``tile_sweep``): the CPU/GPU production path and the oracle the
-Pallas kernels are tested against. Backend selection lives in
-``repro.kernels.ops`` / ``repro.core.measures.pairwise``.
+over ``tile_sweep`` on the (ba*bb, S) pair batch: the CPU/GPU production
+path and the oracle the Pallas kernels are tested against. Backend
+selection lives in ``repro.kernels.ops`` / ``repro.core.measures.pairwise``.
 
 Early-abandon sweep (DESIGN.md §4). Both SP-DTW engines optionally carry a
 per-pair *alive* flag and a per-query threshold through the active-tile
@@ -46,13 +53,13 @@ schedule. Cell costs are non-negative, so once a tile row of the DP is
 complete, ``min_j D(r, j)`` is an admissible lower bound on the final
 value: at the first tile of each new tile row (the ``row_first`` plan bit)
 the running row-min is compared against the threshold and pairs that
-provably cannot beat it are abandoned — their lanes keep streaming through
-the vector engine, but the Pallas kernel skips the whole tile sweep once
-*every* pair of its (A-tile, B-tile) block is dead, and abandoned pairs
-report +INF. With default (+INF) thresholds the engines are bit-identical
-to the unabandoned path. ``alive0`` lets the 1-NN cascade
-(``ops.knn_cascade``) pre-kill pairs already pruned by the lower-bound
-stages, so the DP only ever runs on the survivors.
+provably cannot beat it are abandoned — their elements keep streaming
+through the vector engine, but the Pallas kernel skips the whole tile
+sweep once *every* pair of its (A-tile, B-tile) block is dead, and
+abandoned pairs report +INF. With default (+INF) thresholds the engines
+are bit-identical to the unabandoned path. ``alive0`` lets the 1-NN
+cascade (``ops.knn_cascade``) pre-kill pairs already pruned by the
+lower-bound stages, so the DP only ever runs on the survivors.
 
 In-DP PrunedDTW (DESIGN.md §14). When per-query thresholds are supplied,
 both SP-DTW engines further prune *inside* the DP: after every row of a
@@ -101,25 +108,81 @@ PAIR_BLOCK = (8, 128)    # the Gram kernel's (A rows, B rows) pair block
 _CNT_TILE = (8, 128)     # the smallest lane-legal float32 output block
 
 
-def _pair_column(m: jnp.ndarray) -> jnp.ndarray:
-    """(ba, bb) per-pair block -> (ba*bb, 1) column, pair p = ia*bb + ib.
+def _sweep_rows(xa, yb, w_ref, row_edge, left_in, col_edge, d_ri, xs, ys,
+                tj, thr, *, S: int, ri: int, rj: int, d: int):
+    """Sweep the S rows of one tile for a (ba, bb) pair block.
 
-    The same values as ``m.reshape(-1, 1)``, spelled as row transposes and
-    an aligned sublane concat: Mosaic has no lane-to-sublane shape cast."""
-    return jnp.concatenate([m[i:i + 1, :].T for i in range(m.shape[0])],
-                           axis=0)
+    Each cell of the DP is one (ba, bb) value, pair (ia, ib) at (sublane
+    ia, lane ib), and a tile row is S such values, one per column, so
+    every operation is elementwise over the whole block. The arithmetic
+    is ``spdtw_block.tile_sweep``'s, operation for operation and in the
+    same order, which keeps the values bit-identical to the scan engine
+    (the parity tests assert it): the Hillis-Steele shift by k picks a
+    column statically, and columns below k, which a step leaves
+    unchanged, are not touched. ``row_edge[tj]`` holds the row above on
+    entry and the tile's bottom row on exit; row t reads its diagonal
+    and left edge at ``left_in[t]`` / ``left_in[t + 1]`` and publishes
+    its last cell to ``col_edge[t]``, its cell ``rj`` to ``d_ri`` when
+    t == ri. The weight of cell (t, j) is a scalar from SMEM.
+    """
+    blk = xs.shape[1:]
+    for r in range(d * S):
+        # x's element r of each query along lanes, y's of each corpus
+        # series along sublanes
+        xs[r] = jnp.broadcast_to(xa[:, r:r + 1], blk)
+        ys[r] = jnp.broadcast_to(yb[r:r + 1, :], blk)
+
+    def row(t, carry):
+        xt = [xs[k * S + t] for k in range(d)]
+        c = []
+        for j in range(S):
+            acc = None
+            for k in range(d):
+                dk = (xt[k] - ys[k * S + j]) ** 2
+                acc = dk if acc is None else acc + dk
+            w = w_ref[0, t, j]
+            c.append(jnp.where(w > 0, acc * w, INF))
+        prev = [row_edge[tj, j] for j in range(S)]
+        topleft = [left_in[t]] + prev[:-1]
+        m = [c[j] + jnp.minimum(prev[j], topleft[j]) for j in range(S)]
+        # inject the left-tile boundary as a virtual D_{-1}
+        m[0] = jnp.minimum(m[0], left_in[t + 1] + c[0])
+        s, k = c, 1
+        while k < S:
+            m = m[:k] + [jnp.minimum(m[j], m[j - k] + s[j])
+                         for j in range(k, S)]
+            s = s[:k] + [jnp.minimum(s[j - k] + s[j], INF)
+                         for j in range(k, S)]
+            k *= 2
+        for j in range(S):
+            v = jnp.minimum(m[j], INF)
+            if thr is not None:
+                v = jnp.where(v <= thr, v, INF)
+            row_edge[tj, j] = v
+            m[j] = v
+        col_edge[t] = m[S - 1]
+
+        @pl.when(t == ri)
+        def _():
+            d_ri[...] = m[rj]
+        return carry
+
+    jax.lax.fori_loop(0, S, row, 0)
 
 
 def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
-                       out_ref, cnt_ref, row_edge, col_edge, corner_next, d_ri,
-                       alive, *, S: int, g_out: int, ri: int, rj: int,
-                       ba: int, bb: int, d: int, prune: bool):
+                       out_ref, cnt_ref, row_edge, col_edge, left_in, xs, ys,
+                       corner_next, d_ri, alive, *, S: int, g_out: int,
+                       ri: int, rj: int, d: int, prune: bool):
     """One grid step = one active tile for one (A-stripe, B-stripe) block.
 
-    ``cnt_ref`` is the block's (8, 128) counter tile: [0, 0] counts the
-    tile sweeps run, [0, 1] sums the live pairs over those sweeps."""
+    Per-pair state is (ba, bb) blocks, pair (ia, ib) at (sublane ia,
+    lane ib); edges are stacks of them on leading axes, so every dynamic
+    index (tile column, row) is a leading-axis ref index. ``cnt_ref`` is
+    the block's (8, 128) counter tile: [0, 0] counts the tile sweeps run,
+    [0, 1] sums the live pairs over those sweeps."""
     g = pl.program_id(2)
-    bt = ba * bb
+    blk = alive.shape
 
     @pl.when(g == 0)
     def _():
@@ -128,7 +191,7 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
         # stale cross-block data); alive starts from the cascade's
         # bound-stage survivors (all-ones when no cascade is running)
         row_edge[...] = jnp.full(row_edge.shape, INF, jnp.float32)
-        alive[...] = _pair_column(alive0_ref[...])
+        alive[...] = alive0_ref[...]
         cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.float32)
 
     # early-abandon check at the first tile of each new tile row: the
@@ -136,13 +199,12 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
     # admissible lower bound on every pair's final value (rows past the
     # result tile row are excluded via g <= g_out)
     row_first = meta_ref[g, 6] > 0
-    thr_p = jnp.repeat(thr_ref[...], bb, axis=0)                  # (bt, 1)
+    thr = jnp.broadcast_to(thr_ref[...], blk)           # per query (sublane)
 
     @pl.when(row_first & (g > 0) & (g <= g_out))
     def _():
-        bound = jnp.min(jnp.min(row_edge[...], axis=0), axis=1,
-                        keepdims=True)                            # (bt, 1)
-        alive[...] = alive[...] * (bound <= thr_p).astype(jnp.float32)
+        bound = jnp.min(row_edge[...], axis=(0, 1))            # (ba, bb)
+        alive[...] = alive[...] * (bound <= thr).astype(jnp.float32)
 
     tj = meta_ref[g, 1]
     top_ok = meta_ref[g, 3] > 0
@@ -150,18 +212,17 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
     diag_ok = meta_ref[g, 5] > 0
 
     # --- gather incoming edges (guarded against inactive neighbours) ---
-    inf_row = jnp.full((bt, S), INF, jnp.float32)
-    top_vec = jnp.where(top_ok, row_edge[tj], inf_row)
-    left_vec = jnp.where(left_ok, col_edge[...], inf_row)
+    top = jnp.where(top_ok, row_edge[tj], INF)             # (S, ba, bb)
+    left = jnp.where(left_ok, col_edge[...], INF)
     c_first = jnp.where(
-        g == 0, jnp.zeros((bt, 1), jnp.float32),
+        g == 0, jnp.zeros(blk, jnp.float32),
         jnp.where(diag_ok,
                   jnp.where(left_ok, corner_next[...],
                             # guarded: only read when diag_ok (=> tj > 0);
                             # clamp keeps the untaken branch in-bounds
-                            row_edge[jnp.maximum(tj - 1, 0)][:, S - 1:S]),
-                  jnp.full((bt, 1), INF, jnp.float32)))
-    new_corner = top_vec[:, S - 1:S]
+                            row_edge[jnp.maximum(tj - 1, 0), S - 1]),
+                  INF))
+    new_corner = top[S - 1]
 
     if prune:
         # in-DP PrunedDTW tile skip: a tile whose every incoming edge
@@ -169,10 +230,10 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
         # are non-negative), so its exact pruned sweep is all-+INF rows
         # — skip the sweep whenever no pair in the block is both alive
         # and edge-live, and publish those +INF rows below
-        edge_live = ((jnp.min(top_vec, axis=1, keepdims=True) <= thr_p)
-                     | (jnp.min(left_vec, axis=1, keepdims=True) <= thr_p)
-                     | (c_first <= thr_p))
-        live = alive[...] * edge_live.astype(jnp.float32)         # (bt, 1)
+        edge_live = ((jnp.min(top, axis=0) <= thr)
+                     | (jnp.min(left, axis=0) <= thr)
+                     | (c_first <= thr))
+        live = alive[...] * edge_live.astype(jnp.float32)          # (ba, bb)
     else:
         # the whole tile sweep is skipped once every pair is dead
         live = alive[...]
@@ -180,7 +241,8 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
 
     # counters: a sweep runs iff some pair is live, so the block's live
     # count is its contribution to the swept pairs, and 0 when skipped
-    n_live = jnp.sum(live, axis=0, keepdims=True)                 # (1, 1)
+    n_live = jnp.sum(jnp.sum(live, axis=1, keepdims=True), axis=0,
+                     keepdims=True)                                # (1, 1)
     sub = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 1)
     cnt_ref[...] += jnp.where(
@@ -189,22 +251,16 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
 
     @pl.when(do_sweep)
     def _():
+        # tile-stacked layouts: tile ti's d channel planes are one slab,
+        # queries on sublanes for A, corpus series on lanes for B
         ti = meta_ref[g, 0]
-        # tile-stacked layout: tile ti's d channel planes are one slab
-        xa = a_ref[ti]                                             # (ba, d*S)
-        yb = b_ref[tj]                                             # (bb, d*S)
-        x, y = _pair_batch(xa, yb, ba, bb)                         # (bt, d*S)
-        w = w_ref[0]                                               # (S, S)
-
-        d_last, rightcol, dri = tile_sweep(x, y, w, top_vec, left_vec,
-                                           c_first, S=S, ri=ri, d=d,
-                                           thr=thr_p if prune else None)
-
-        # --- publish edges for downstream tiles of this pair block ---
         corner_next[...] = new_corner
-        row_edge[tj] = d_last
-        col_edge[...] = rightcol
-        d_ri[...] = dri
+        row_edge[tj] = top
+        left_in[0] = c_first
+        left_in[pl.ds(1, S)] = left
+        _sweep_rows(a_ref[ti], b_ref[tj], w_ref, row_edge, left_in,
+                    col_edge, d_ri, xs, ys, tj, thr if prune else None,
+                    S=S, ri=ri, rj=rj, d=d)
 
     if prune:
         @pl.when(~do_sweep)
@@ -213,19 +269,17 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
             # rows (never stale state — downstream tiles of this pair
             # block consume these edges)
             corner_next[...] = new_corner
-            row_edge[tj] = inf_row
-            col_edge[...] = inf_row
-            d_ri[...] = inf_row
+            row_edge[tj] = jnp.full(top.shape, INF, jnp.float32)
+            col_edge[...] = jnp.full(top.shape, INF, jnp.float32)
+            d_ri[...] = jnp.full(blk, INF, jnp.float32)
 
     # capture at the tile holding the global result cell (NOT the last
     # active tile — the support may be active past the corner, or raw user
     # weights may not reach it at all; see ``result_tile_step``); abandoned
-    # pairs report +INF (their lanes may hold garbage from skipped sweeps)
+    # pairs report +INF (their cells may hold garbage from skipped sweeps)
     @pl.when(g == g_out)
     def _():
-        res = d_ri[:, rj:rj + 1]
-        ok = alive[...].reshape(ba, bb) > 0
-        out_ref[...] = jnp.where(ok, res.reshape(ba, bb), INF)
+        out_ref[...] = jnp.where(alive[...] > 0, d_ri[...], INF)
 
 
 @functools.partial(jax.jit,
@@ -234,14 +288,17 @@ def _gram_spdtw_kernel(meta_ref, a_ref, b_ref, w_ref, thr_ref, alive0_ref,
 def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
                      g_out, ba, bb, d, prune, interpret):
     """(Nap, Nbp) Gram values, and two int32 counts summed over the pair
-    blocks: the tile sweeps run, and the live pairs over those sweeps."""
-    Ti, Nap, _ = A.shape            # tile-stacked: (Ti, Nap, d*S)
-    Nbp = B.shape[1]
+    blocks: the tile sweeps run, and the live pairs over those sweeps.
+
+    A is tile-stacked with queries on sublanes, (Ti, Nap, d*S); B with
+    time on sublanes and corpus series on lanes, (Ti, d*S, Nbp)."""
+    Ti, Nap, _ = A.shape
+    Nbp = B.shape[2]
     last = T_orig - 1
     ri, rj = last % S, last % S
     grid = (Nap // ba, Nbp // bb, n_active)
     kernel = functools.partial(_gram_spdtw_kernel, S=S, g_out=g_out,
-                               ri=ri, rj=rj, ba=ba, bb=bb, d=d, prune=prune)
+                               ri=ri, rj=rj, d=d, prune=prune)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -249,8 +306,10 @@ def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
             # index maps constant in the inner axes: each stripe is copied to
             # VMEM once per (A-tile, B-tile) and revisited for every g
             pl.BlockSpec((Ti, ba, d * S), lambda i, j, g, m: (0, i, 0)),
-            pl.BlockSpec((Ti, bb, d * S), lambda i, j, g, m: (0, j, 0)),
-            pl.BlockSpec((1, S, S), lambda i, j, g, m: (m[g, 2], 0, 0)),
+            pl.BlockSpec((Ti, d * S, bb), lambda i, j, g, m: (0, 0, j)),
+            # the tile's weights, read one scalar a cell
+            pl.BlockSpec((1, S, S), lambda i, j, g, m: (m[g, 2], 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((ba, 1), lambda i, j, g, m: (i, 0)),    # thresholds
             pl.BlockSpec((ba, bb), lambda i, j, g, m: (i, j)),   # alive0
         ],
@@ -260,11 +319,14 @@ def _gram_spdtw_call(meta, A, B, blocks, thr, alive0, *, S, n_active, T_orig,
             pl.BlockSpec(_CNT_TILE, lambda i, j, g, m: (i, j)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((Ti, ba * bb, S), jnp.float32),  # row_edge
-            pltpu.VMEM((ba * bb, S), jnp.float32),    # col_edge
-            pltpu.VMEM((ba * bb, 1), jnp.float32),    # corner_next
-            pltpu.VMEM((ba * bb, S), jnp.float32),    # d_ri capture
-            pltpu.VMEM((ba * bb, 1), jnp.float32),    # alive flags
+            pltpu.VMEM((Ti, S, ba, bb), jnp.float32),   # row_edge
+            pltpu.VMEM((S, ba, bb), jnp.float32),       # col_edge
+            pltpu.VMEM((S + 1, ba, bb), jnp.float32),   # corner, left edge
+            pltpu.VMEM((d * S, ba, bb), jnp.float32),   # x, lane-broadcast
+            pltpu.VMEM((d * S, ba, bb), jnp.float32),   # y, sublane-broadcast
+            pltpu.VMEM((ba, bb), jnp.float32),          # corner_next
+            pltpu.VMEM((ba, bb), jnp.float32),          # d_ri: result cell
+            pltpu.VMEM((ba, bb), jnp.float32),          # alive flags
         ],
     )
     ni, nj = grid[0], grid[1]
@@ -348,7 +410,8 @@ def gram_spdtw_block(A: jnp.ndarray, B: jnp.ndarray, bsp: BlockSparsePaths,
     thr, alive = _pad_abandon_state(thresholds, alive0, Na, Nb, Nap, Nbp)
     out, sweeps, live = _gram_spdtw_call(
         jnp.asarray(meta), to_tile_stack(A, bsp.tile, bsp.T, n_to=Nap),
-        to_tile_stack(B, bsp.tile, bsp.T, n_to=Nbp), jnp.asarray(bsp.blocks),
+        to_tile_stack(B, bsp.tile, bsp.T, n_to=Nbp).transpose(0, 2, 1),
+        jnp.asarray(bsp.blocks),
         thr, alive, S=bsp.tile, n_active=n_active, T_orig=T_orig,
         g_out=g_out, ba=ba, bb=bb, d=d, prune=thresholds is not None,
         interpret=interpret)

@@ -30,7 +30,10 @@ unused here).
 
 The per-tile DP (``tile_sweep``: row loop + Hillis-Steele min-plus lane
 scan, edge injection from the neighbouring tiles) is pure jnp on values and
-shared verbatim with ``gram_block.py``'s Pallas kernel and jnp scan engine.
+shared verbatim with ``gram_block.py``'s jnp scan engine. ``gram_block``'s
+Pallas kernel holds a pair block as one (ba, bb) value per cell and shares
+the arithmetic, not the code: the same operations in the same order, held
+bit-identical by the parity tests.
 """
 from __future__ import annotations
 
@@ -82,8 +85,8 @@ def tile_cost_row(x, y, w, t, *, S: int, d: int = 1):
     *dependent* DTW of the summed local cost under one shared path —
     exactly what the dense core DPs (``core.dtw.local_cost``) compute.
     Masked cells (w == 0) read +INF. ``t`` may be traced (the row loop
-    index). Shared by the hard sweeps here / in ``gram_block``; the soft
-    twin lives in ``soft_block``.
+    index). Shared by the hard sweeps here / in ``gram_block``'s scan
+    engines; the soft twin lives in ``soft_block``.
     """
     wt = _pick(w, t, jax.lax.broadcasted_iota(jnp.int32, w.shape, 0), 0)
     xlane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
@@ -101,8 +104,9 @@ def tile_sweep(x, y, w, top_vec, left_vec, c_first, *, S: int, ri: int,
     """Sweep one S x S tile of the SP-DTW DP for a batch of pairs.
 
     Pure jnp on values (no refs), so it is shared verbatim by the single-pair
-    Pallas kernel here, the fused Gram kernel in ``gram_block.py`` and the
-    jnp scan engine (same math => parity by construction). Row- and
+    Pallas kernel here and the jnp scan engines in ``gram_block.py``; the
+    fused Gram kernel there repeats its arithmetic in a pair-block layout
+    (``gram_block._sweep_rows``), held bit-identical by tests. Row- and
     column-indexed reads and writes at the loop index go through lane
     selects (``_pick`` / ``jnp.where``), never a value-level dynamic slice,
     so the one body lowers both in XLA and in Mosaic.

@@ -74,14 +74,15 @@ def _sds(sharding, shape, dtype=jnp.float32):
                          ids=["exact", "thresholds_alive0"])
 def test_gram_kernel_compiles_for_v5e(one_chip, no_persistent_cache, T, d,
                                       n_corpus, prune):
-    """The fused Gram kernel; d = 2 covers the pallas record's
-    MULTIVARIATE claim."""
+    """The fused Gram kernel, queries tile-stacked on sublanes and the
+    corpus on lanes; d = 2 covers the pallas record's MULTIVARIATE
+    claim."""
     bsp, meta, g_out = _plan(T)
     S, Ti = bsp.tile, bsp.T // bsp.tile
     compiled = _gram_spdtw_call.lower(
         _sds(one_chip, meta.shape, jnp.int32),
         _sds(one_chip, (Ti, N_QUERY, d * S)),
-        _sds(one_chip, (Ti, n_corpus, d * S)),
+        _sds(one_chip, (Ti, d * S, n_corpus)),
         _sds(one_chip, bsp.blocks.shape), _sds(one_chip, (N_QUERY, 1)),
         _sds(one_chip, (N_QUERY, n_corpus)),
         S=S, n_active=meta.shape[0], T_orig=T, g_out=g_out, ba=8, bb=128,
