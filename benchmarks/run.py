@@ -1,9 +1,8 @@
 """Benchmark entry point: ``PYTHONPATH=src python -m benchmarks.run``.
 
 One function per paper table/figure + the kernel wall-clock micro-bench +
-the search-cascade bench + the roofline table (from dry-run artifacts, if
-present). Prints a final ``name,us_per_call,derived`` CSV summary per the
-harness contract.
+the search-cascade bench. Prints a final ``name,us_per_call,derived`` CSV
+summary per the harness contract.
 
 Full-protocol runs: ``python -m benchmarks.run --full`` (slower, bigger
 test splits). ``--smoke`` runs tiny shapes in seconds — a CI-grade sanity
@@ -192,17 +191,6 @@ def main(argv=None):
         run_bench("table4_svm", lambda: table4_svm.run(fast=fast))
         run_bench("occupancy_fig", lambda: occupancy_fig.run(fast=fast))
 
-        def roofline_bench():
-            from . import roofline
-            cells = roofline.load_artifacts()
-            if not cells:
-                return {"note":
-                        "no dry-run artifacts; run repro.launch.dryrun"}
-            print(roofline.table(cells))
-            return roofline.summary(cells)
-
-        run_bench("roofline", roofline_bench)
-
     # ---- harness contract: name,us_per_call,derived ----
     print("\nname,us_per_call,derived")
     kw = results.get("kernel_walltime", {})
@@ -269,11 +257,6 @@ def main(argv=None):
         for m, r in results["table4_svm"]["mean_rank"].items():
             print(f"table4/mean_rank/{m},"
                   f"{timings.get('table4_svm', 0)*1e6:.0f},{r:.2f}")
-    if "roofline" in results and "ok" in results.get("roofline", {}):
-        r = results["roofline"]
-        print(f"roofline/cells_ok,{r['ok']},count")
-        print(f"roofline/cells_skipped,{r['skipped']},count")
-        print(f"roofline/cells_error,{r['errors']},count")
     print(f"\nall benchmark artifacts: {os.path.join(art, '*.json')}")
 
 

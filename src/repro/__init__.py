@@ -20,8 +20,8 @@ Layer map (one directory per layer; see README.md and DESIGN.md):
              centroid fitting; §8)
   data/      offline synthetic-UCR datasets (§7.1)
 
-This module re-exports the supported public API; the training stack
-(models/, train/, configs/) is imported explicitly by its entry points.
+This module re-exports the supported public API; the drivers under
+launch/ are not re-exported and run as ``python -m repro.launch.<name>``.
 
 The supported entry point is the fitted engine (DESIGN.md §12):
 

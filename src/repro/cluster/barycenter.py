@@ -12,7 +12,7 @@ backward walks the cached tile plan in reverse (the expected-alignment
 sweep of DESIGN.md §11) — both passes scale with active tiles, so every
 Adam step of the fit pays work proportional to the learned support, not
 O(T^2). The centroid is fitted by plain first-order optimization — Adam
-via the in-house ``train.optimizer.AdamW`` (weight decay off),
+(``adam_init`` / ``adam_update`` below: f32 parameters, no weight decay),
 ``lax.scan`` over steps. Everything here is pure and traceable:
 ``soft_barycenter`` runs unchanged inside jit / vmap / shard_map (the
 sharded fitting job in ``launch/cluster.py`` vmaps it over a centroid
@@ -21,13 +21,44 @@ artifact — which the learned support always is (DESIGN.md §2).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.soft_block import soft_spdtw_batch
-from repro.train.optimizer import AdamW
+
+ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.95, 1e-8
+
+
+class AdamState(NamedTuple):
+    """Adam's step count and first / second moments (pytrees like the
+    parameters)."""
+    step: jnp.ndarray
+    m: Any
+    v: Any
+
+
+def adam_init(params) -> AdamState:
+    """Zero moments for a pytree of f32 ``params``."""
+    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    return AdamState(jnp.zeros((), jnp.int32), zeros, zeros)
+
+
+def adam_update(grads, state: AdamState, params, lr: float):
+    """One bias-corrected Adam step (b1 0.9, b2 0.95, eps 1e-8, no
+    weight decay). Returns (new params, new state)."""
+    step = state.step + 1
+    b1c = 1.0 - ADAM_B1 ** step.astype(jnp.float32)
+    b2c = 1.0 - ADAM_B2 ** step.astype(jnp.float32)
+    m = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g,
+                     state.m, grads)
+    v = jax.tree.map(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g,
+                     state.v, grads)
+    new = jax.tree.map(
+        lambda p, m, v: p - lr * ((m / b1c) / (jnp.sqrt(v / b2c) + ADAM_EPS)),
+        params, m, v)
+    return new, AdamState(step, m, v)
 
 
 def barycenter_loss(z: jnp.ndarray, X: jnp.ndarray, weights: jnp.ndarray,
@@ -50,8 +81,7 @@ def barycenter_loss(z: jnp.ndarray, X: jnp.ndarray, weights: jnp.ndarray,
 def soft_barycenter(X: jnp.ndarray, weights: jnp.ndarray, gamma: float = 0.1,
                     *, init: Optional[jnp.ndarray] = None, steps: int = 100,
                     lr: float = 0.05,
-                    sample_weights: Optional[jnp.ndarray] = None,
-                    optimizer: Optional[AdamW] = None
+                    sample_weights: Optional[jnp.ndarray] = None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fit one barycenter by Adam on the soft-SP-DTW VJP.
 
@@ -74,14 +104,13 @@ def soft_barycenter(X: jnp.ndarray, weights: jnp.ndarray, gamma: float = 0.1,
                 jnp.maximum(jnp.sum(sw), 1e-8)
     else:
         z0 = jnp.asarray(init, jnp.float32)
-    opt = optimizer or AdamW(lr=lr, weight_decay=0.0)
-    state = opt.init(z0)
+    state = adam_init(z0)
 
     def step(carry, _):
         z, st = carry
         loss, g = jax.value_and_grad(barycenter_loss)(
             z, X, weights, gamma, sample_weights)
-        z2, st2 = opt.update(g, st, z)
+        z2, st2 = adam_update(g, st, z, lr)
         return (z2, st2), loss
 
     (z, _), losses = jax.lax.scan(step, (z0, state), None, length=steps)

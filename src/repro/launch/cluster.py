@@ -6,10 +6,10 @@ the centroid stripe (k, T) row-sharded, the member set X (N, T) and the
 (k, N) assignment-weight matrix riding along (weights sharded with the
 centroids). Each chip runs the full Adam loop
 (``cluster.barycenter.soft_barycenter``: block-sparse active-tile stash
-forward, reverse active-tile expected-alignment backward,
-``train.optimizer.AdamW``) on its centroid rows — no cross-chip
-communication at all until the final all-gather of the fitted stripe,
-and per-step work on both passes proportional to the learned support.
+forward, reverse active-tile expected-alignment backward, Adam) on its
+centroid rows — no cross-chip communication at all until the final
+all-gather of the fitted stripe, and per-step work on both passes
+proportional to the learned support.
 The learned weight grid is resolved host-side once per job and closed
 over as a constant, exactly like the Gram job; ``--dryrun`` lowers +
 compiles on the 512-chip production mesh from ShapeDtypeStructs only.

@@ -14,8 +14,7 @@ over as constants. One all_gather reassembles the Gram matrix; work is
 embarrassingly parallel, so the roofline is pure compute.
 
 ``--dryrun`` lowers + compiles the job on the 512-chip production mesh
-(ShapeDtypeStructs only), proving the paper plane shards, same as the LM
-cells (EXPERIMENTS.md §Dry-run).
+(ShapeDtypeStructs only).
 
 ``--mode knn`` swaps the all-pairs Gram for the exact-1-NN cascade
 (``kernels.ops.knn_cascade``): queries are sharded row-wise, each chip
@@ -149,9 +148,6 @@ if __name__ == "__main__":
     ap.add_argument("--mode", default="gram", choices=("gram", "knn"))
     args = ap.parse_args()
     if args.dryrun:
-        # production mesh needs the fake-device env BEFORE jax init;
-        # re-exec pattern documented in dryrun.py — here we require the
-        # caller set it (launch/dryrun_gram.sh does)
         from repro.launch.mesh import make_production_mesh
         mesh = make_production_mesh(multi_pod=args.multi_pod)
         out = run(args.n, args.t, args.kind, dryrun=True, mesh=mesh,

@@ -1,8 +1,11 @@
-"""Production mesh construction (assignment-specified).
+"""Named device meshes for the sharded jobs (``launch/gram.py``,
+``launch/cluster.py``) and their tests.
 
-A FUNCTION, not a module-level constant, so importing this module never
-touches jax device state. Single pod: 16x16 = 256 chips ("data", "model");
-multi-pod: 2x16x16 = 512 chips ("pod", "data", "model").
+Functions, not module-level constants, so importing this module never
+touches jax device state. ``make_host_mesh`` spans the devices present;
+``make_production_mesh`` is the 16x16 ("data", "model") mesh, or 2x16x16
+("pod", "data", "model") with ``multi_pod``, that the jobs' ``--dryrun``
+compiles against.
 """
 from __future__ import annotations
 
@@ -22,5 +25,5 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / local training)."""
+    """Small mesh over whatever devices exist (tests, local jobs)."""
     return make_mesh((data, model), ("data", "model"))

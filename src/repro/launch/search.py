@@ -2,10 +2,9 @@
 
 The serving side of the paper plane: a fixed corpus is indexed once
 (``Measure.build_index`` — envelopes, support windows, block-sparse tile
-plan), then a stream of 1-NN queries is served continuous-batching style,
-mirroring ``launch/serve.py``'s bookkeeping: requests join at the next
-step boundary, each step runs one cascade batch, finished slots free up
-for the next arrivals. Every batch runs bounds -> survivors -> fused
+plan), then a stream of 1-NN queries is served continuous-batching style:
+requests join at the next step boundary, each step runs one cascade
+batch, finished slots free up for the next arrivals. Every batch runs bounds -> survivors -> fused
 masked DP (``kernels.ops.knn_cascade``) and reports per-stage prune
 rates; results are bit-identical to the full-Gram path.
 
@@ -326,13 +325,12 @@ def stream_search(engine: SearchEngine, queries: Sequence[np.ndarray],
                   batch: int = 16,
                   arrivals_per_step: Optional[int] = None
                   ) -> List[QueryResult]:
-    """Serve a query stream with continuous batching (serve.py-style).
+    """Serve a query stream with continuous batching.
 
     Requests arrive ``arrivals_per_step`` at a time (None = all up front)
     and join the pending queue; each step drains up to ``batch`` of them
     into one cascade call. A request admitted while a step is in flight
-    waits for the next boundary — the same join-at-step-boundary rule as
-    the decode loop in ``launch/serve.py``.
+    waits for the next step boundary.
     """
     if arrivals_per_step is not None and arrivals_per_step <= 0:
         raise ValueError("arrivals_per_step must be positive (or None for "

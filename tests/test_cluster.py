@@ -1,6 +1,6 @@
-"""Centroid workload (DESIGN.md §10): barycenter fixed point, class
-centroids, k-means loop, centroid-seeded cascade exactness, centroid
-serving mode, and the sharded fitting job."""
+"""Centroid workload (DESIGN.md §10): the barycenter's Adam, barycenter
+fixed point, class centroids, k-means loop, centroid-seeded cascade
+exactness, centroid serving mode, and the sharded fitting job."""
 import numpy as np
 import pytest
 import jax
@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from repro.classify import centroid_error_series, knn_error_series
 from repro.cluster import (CentroidModel, fit_class_centroids,
                            nearest_centroid, soft_barycenter, soft_kmeans)
+from repro.cluster.barycenter import adam_init, adam_update
 from repro.core import learn_sparse_paths, make_measure
 from repro.core.dtw import wdtw
 from repro.data import load
@@ -62,6 +63,57 @@ def test_barycenter_zero_sample_weights_frozen(cbf):
     np.testing.assert_allclose(np.asarray(z), np.asarray(init), atol=1e-6)
     assert float(losses[-1]) == 0.0
 
+
+
+# ------------------------------------------------------------------ Adam
+def test_adamw_minimizes_quadratic():
+    params = {"w": jnp.asarray([5.0, -3.0])}
+    state = adam_init(params)
+    for _ in range(200):
+        g = jax.tree.map(lambda p: 2 * p, params)   # d/dw ||w||^2
+        params, state = adam_update(g, state, params, 0.1)
+    assert float(jnp.abs(params["w"]).max()) < 0.1
+
+
+def _textbook_adam(p, grad, lr, steps, b1=0.9, b2=0.95, eps=1e-8):
+    """Adam with bias correction, in float64 NumPy, one leaf."""
+    m = np.zeros_like(p)
+    v = np.zeros_like(p)
+    for t in range(1, steps + 1):
+        g = grad(p, t)
+        m = b1 * m + (1 - b1) * g
+        v = b2 * v + (1 - b2) * g * g
+        p = p - lr * (m / (1 - b1 ** t)) / (np.sqrt(v / (1 - b2 ** t)) + eps)
+    return p
+
+
+@pytest.mark.parametrize("shapes", [{"s": ()}, {"v": (7,)},
+                                    {"m": (3, 4)}, {"a": (5,), "b": (2, 3)}],
+                         ids=["scalar", "vector", "matrix", "pytree"])
+def test_adam_matches_textbook_update(shapes):
+    """Five steps of the barycenter's Adam on a noisy quadratic equal the
+    textbook update leaf by leaf, to float32 rounding."""
+    rng = np.random.default_rng(0)
+    p0 = {k: rng.normal(size=s) for k, s in shapes.items()}
+    target = {k: rng.normal(size=s) for k, s in shapes.items()}
+    noise = {k: rng.normal(size=(6,) + s) for k, s in shapes.items()}
+
+    def grad(k):
+        return lambda p, t: 2 * (p - target[k]) + noise[k][t]
+
+    lr, steps = 0.05, 5
+    params = {k: jnp.asarray(v, jnp.float32) for k, v in p0.items()}
+    state = adam_init(params)
+    for t in range(1, steps + 1):
+        g = {k: jnp.asarray(grad(k)(np.asarray(params[k], np.float64), t),
+                            jnp.float32) for k in params}
+        params, state = adam_update(g, state, params, lr)
+    assert int(state.step) == steps
+    for k in shapes:
+        assert params[k].dtype == jnp.float32 and params[k].shape == shapes[k]
+        want = _textbook_adam(p0[k], grad(k), lr, steps)
+        np.testing.assert_allclose(np.asarray(params[k]), want, rtol=1e-5,
+                                   atol=1e-6)
 
 # -------------------------------------------------------- class centroids
 def test_fit_class_centroids_model(cbf, fitted):

@@ -1,10 +1,11 @@
-"""The JAX APIs the launch/train code calls directly + import surface.
+"""The JAX APIs the launch code calls directly + import surface.
 
 The tree targets one JAX (0.9.0) and calls its public API with no shims:
-``jax.shard_map`` (with ``check_vma``), ``jax.lax.optimization_barrier``
-(which has its own differentiation rule), ``jax.set_mesh`` as a context
+``jax.shard_map`` (with ``check_vma``), ``jax.set_mesh`` as a context
 manager, and ``launch.mesh.make_mesh``. Each test pins the behaviour the
-callers rely on. The import sweep keeps every public module loadable.
+callers rely on; ``jax.lax.optimization_barrier`` (which has its own
+differentiation rule) is pinned too, though no module calls it now. The
+import sweep keeps every public module loadable.
 """
 import importlib
 
@@ -44,7 +45,7 @@ def test_shard_map_shim_replicated_operand():
 
 def test_optimization_barrier_identity_and_grad():
     """Value passes through untouched, and the barrier is transparent to
-    differentiation (the models put it on the residual stream)."""
+    differentiation."""
     x = jnp.asarray([1.0, -2.0, 3.5])
     np.testing.assert_array_equal(
         np.asarray(jax.lax.optimization_barrier(x)), np.asarray(x))

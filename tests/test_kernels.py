@@ -169,58 +169,3 @@ def test_wavefront_krdtw_long_series_stable():
     got = np.asarray(wavefront_log_krdtw(x, y, 1.0, interpret=True))
     assert np.isfinite(got).all()
 
-
-# ------------------------------------------------------- flash attention
-class TestFlashAttention:
-    """Custom-VJP flash attention vs plain chunked attention (fwd + grads)."""
-
-    def _mk(self, B=2, Sq=32, Skv=32, Hq=4, Hkv=2, hd=8, dv=8, seed=0):
-        rng = np.random.default_rng(seed)
-        q = jnp.asarray(rng.normal(size=(B, Sq, Hq, hd)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(B, Skv, Hkv, hd)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(B, Skv, Hkv, dv)).astype(np.float32))
-        return q, k, v
-
-    @pytest.mark.parametrize("causal,window", [(True, None), (False, None),
-                                               (True, 8)])
-    def test_forward_matches_reference(self, causal, window):
-        from repro.models.flash import flash_attention
-        from repro.models.layers import attention
-        q, k, v = self._mk()
-        got = flash_attention(q, k, v, causal, window, 0, 16, None)
-        want = attention(q, k, v, causal=causal, window=window, kv_chunk=16)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5)
-
-    @pytest.mark.parametrize("dv", [8, 6])
-    def test_gradients_match_autodiff_reference(self, dv):
-        from repro.models.flash import flash_attention
-        from repro.models.layers import attention
-        q, k, v = self._mk(dv=dv)
-
-        def loss_flash(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, True, None, 0, 16,
-                                           None) ** 2)
-
-        def loss_ref(q, k, v):
-            return jnp.sum(attention(q, k, v, causal=True,
-                                     kv_chunk=16) ** 2)
-
-        g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-4, rtol=5e-3)
-
-    @settings(max_examples=8, deadline=None)
-    @given(st.integers(0, 10_000))
-    def test_property_flash_grads(self, seed):
-        from repro.models.flash import flash_attention
-        from repro.models.layers import attention
-        q, k, v = self._mk(B=1, Sq=16, Skv=16, Hq=2, Hkv=1, hd=4, dv=4,
-                           seed=seed)
-        f = jax.grad(lambda q: jnp.sum(
-            flash_attention(q, k, v, True, None, 0, 8, None)))(q)
-        r = jax.grad(lambda q: jnp.sum(
-            attention(q, k, v, causal=True, kv_chunk=8)))(q)
-        np.testing.assert_allclose(np.asarray(f), np.asarray(r), atol=1e-4)
